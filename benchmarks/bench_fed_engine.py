@@ -75,6 +75,7 @@ from repro.fed.engine import (fused_compile_count, make_engine,
                               reset_scbf_compile_count, scbf_compile_count)
 from repro.fed.scheduler import SyncScheduler
 from repro.fed.strategy import RoundContribution, ScbfSum
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.mlp_net import init_mlp
 from repro.obs import EMITTER, metrics as obsm, report as obs_report, \
     trace as obstrace
@@ -596,6 +597,7 @@ def run_pod_scaling(quick: bool = True, pods: int = 1):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="CI-sized shards/model (the default full run is "

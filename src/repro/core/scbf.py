@@ -134,17 +134,9 @@ def _evaluate(params, x, y, batch: int = 8192, neuron_masks=None):
 
 
 def _compile_counts():
-    """(scbf, fused) jit-cache sizes for the run_end watchdog delta.
-
-    None when the pinned-jax introspection hook is unavailable — the
-    flight recorder then simply omits the compile counters rather than
-    failing a training run over a diagnostics read.
-    """
+    """(scbf, fused) jit-cache sizes for the run_end watchdog delta."""
     from repro.fed.engine import fused_compile_count, scbf_compile_count
-    try:
-        return scbf_compile_count(), fused_compile_count()
-    except RuntimeError:
-        return None
+    return scbf_compile_count(), fused_compile_count()
 
 
 def _finish_telemetry(result: RunResult, counts0) -> None:
@@ -154,8 +146,8 @@ def _finish_telemetry(result: RunResult, counts0) -> None:
     if rec is None:
         return
     tel = dict(rec.counters)
-    counts1 = _compile_counts()
-    if counts0 is not None and counts1 is not None:
+    if counts0 is not None:
+        counts1 = _compile_counts()
         tel["scbf_compiles"] = counts1[0] - counts0[0]
         tel["fused_compiles"] = counts1[1] - counts0[1]
     result.telemetry = tel

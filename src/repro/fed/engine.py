@@ -570,7 +570,9 @@ class BatchedEngine:
         return "pod" if self.mesh is not None else None
 
     def _mesh_ctx(self):
-        return self.mesh if self.mesh is not None else \
+        # jax.set_mesh (not the legacy ``with mesh:``) is what makes the
+        # pod axis visible to vmap's spmd_axis_name
+        return jax.set_mesh(self.mesh) if self.mesh is not None else \
             contextlib.nullcontext()
 
     def _gather(self, participants: np.ndarray):
@@ -988,31 +990,15 @@ def scbf_compile_count() -> int:
 
     One entry per traced (shape, static-args) combination — the number
     tests and benchmarks assert stays at "one per bucket", not "one per
-    distinct P" (clear with ``reset_scbf_compile_count`` first).
-
-    Reads jit's cache through the ``_cache_size`` introspection hook,
-    which is not public API: if a jax upgrade removes it, fail with an
-    actionable error instead of an AttributeError deep in a test (CI
-    pins jax==0.4.37; there is no public per-function alternative —
-    ``jax.monitoring`` compile events are process-global).
+    distinct P" (clear with ``reset_scbf_compile_count`` first).  Reads
+    jit's ``_cache_size`` hook: ``jax.monitoring`` compile events are
+    process-global, and there is no public per-function count.
     """
-    try:
-        return int(_scbf_pass._cache_size())
-    except AttributeError as e:
-        raise RuntimeError(
-            "jit cache introspection (_cache_size) is unavailable on this "
-            "jax version; compile-count assertions need the pinned "
-            "jax==0.4.37 API or an equivalent hook") from e
+    return int(_scbf_pass._cache_size())
 
 
 def reset_scbf_compile_count() -> None:
-    try:
-        _scbf_pass._clear_cache()
-    except AttributeError as e:
-        raise RuntimeError(
-            "jit cache clearing (_clear_cache) is unavailable on this "
-            "jax version; compile-count assertions need the pinned "
-            "jax==0.4.37 API or an equivalent hook") from e
+    _scbf_pass._clear_cache()
 
 
 def fused_compile_count() -> int:
@@ -1021,28 +1007,15 @@ def fused_compile_count() -> int:
     The fused acceptance bar is "<= 2 compiles across a varying-P
     trace": because the plan is padded to a run-constant (S, B), every
     chunk — including the short tail — shares one compiled program.
-    Same ``_cache_size`` introspection caveat as ``scbf_compile_count``.
     """
     scbf, fedavg = _fused_programs()
-    try:
-        return int(scbf._cache_size() + fedavg._cache_size())
-    except AttributeError as e:
-        raise RuntimeError(
-            "jit cache introspection (_cache_size) is unavailable on this "
-            "jax version; compile-count assertions need the pinned "
-            "jax==0.4.37 API or an equivalent hook") from e
+    return int(scbf._cache_size() + fedavg._cache_size())
 
 
 def reset_fused_compile_count() -> None:
     scbf, fedavg = _fused_programs()
-    try:
-        scbf._clear_cache()
-        fedavg._clear_cache()
-    except AttributeError as e:
-        raise RuntimeError(
-            "jit cache clearing (_clear_cache) is unavailable on this "
-            "jax version; compile-count assertions need the pinned "
-            "jax==0.4.37 API or an equivalent hook") from e
+    scbf._clear_cache()
+    fedavg._clear_cache()
 
 
 def make_engine(kind: str, clients, batch_size: int, epochs: int,

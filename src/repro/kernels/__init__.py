@@ -11,7 +11,7 @@ bandwidth-bound reduction + masked rewrite.  Three fused kernels:
   apoz          — zero-fraction accumulation over activation tiles for
                   the APoZ pruning statistic
 
-``ops.py`` exposes jit'd wrappers (with interpret=True on CPU);
+``ops.py`` exposes jit'd wrappers (interpreted off the TPU);
 ``ref.py`` holds the pure-jnp oracles the tests sweep against.
 """
 from repro.kernels.ops import (channel_norms, select_mask, apoz_counts,

@@ -24,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.models.mlp_net import mlp_activations
 
@@ -31,45 +32,75 @@ DEFAULT_BB = 512
 DEFAULT_BN = 256
 
 
-def _apoz_kernel(a_ref, cnt_ref):
-    b = pl.program_id(0)
+def default_interpret() -> bool:
+    """Interpret Pallas kernels unless the default backend is a TPU.
 
-    @pl.when(b == 0)
+    Read when a kernel is traced, never when a module is imported, so
+    the choice follows the backend the program actually runs on.
+    """
+    return jax.default_backend() != "tpu"
+
+
+def _apoz_kernel(a_ref, cnt_ref):
+    # grid = (column blocks, batch blocks): the batch reduction is the
+    # last grid axis, so each output block accumulates over consecutive
+    # steps, which is what the compiled pipeline requires
+    @pl.when(pl.program_id(1) == 0)
     def _():
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
     zeros = (a_ref[...] == 0).astype(jnp.int32)
-    cnt_ref[...] += jnp.sum(zeros, axis=0)
+    cnt_ref[...] += jnp.sum(zeros, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "bn", "interpret"))
 def apoz_counts_pallas(acts: jnp.ndarray, bb: int = DEFAULT_BB,
-                       bn: int = DEFAULT_BN, interpret: bool = True):
-    """acts (B, N) -> zero counts (N,) int32."""
+                       bn: int = DEFAULT_BN, interpret: bool | None = None):
+    """acts (B, N) -> zero counts (N,) int32.
+
+    ``interpret=None`` picks interpret mode from the backend at trace
+    time (``default_interpret``).  The output is one (1, bn) row block
+    per column block, so any lane-aligned ``bn`` (or ``bn == N``)
+    compiles for the TPU.
+    """
     b, n = acts.shape
     assert b % bb == 0 and n % bn == 0, (acts.shape, bb, bn)
-    grid = (b // bb, n // bn)
-    return pl.pallas_call(
+    if interpret is None:
+        interpret = default_interpret()
+    out = pl.pallas_call(
         _apoz_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((bb, bn), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((bn,), lambda i, j: (j,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
+        grid=(n // bn, b // bb),
+        in_specs=[pl.BlockSpec((bb, bn), lambda j, i: (i, j))],
+        out_specs=pl.BlockSpec((1, bn), lambda j, i: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(acts)
+    return out[0]
+
+
+def column_block(n: int):
+    """Column block width for an N-wide activation, or None if N does
+    not tile: DEFAULT_BN when it divides N, the whole width when N is
+    narrower (a full-width block needs no lane alignment)."""
+    if n % DEFAULT_BN == 0:
+        return DEFAULT_BN
+    return n if n < DEFAULT_BN else None
 
 
 def _zero_fraction(act: jnp.ndarray) -> jnp.ndarray:
     """Per-column exact-zero fraction of one (B, N) activation block.
 
     Dispatches to the Pallas counting kernel when the block tiles
-    evenly (count / B equals the jnp mean exactly for any realistic
+    (count / B equals the jnp mean exactly for any realistic
     validation-set size, so the dispatch never changes the statistic)
-    and falls back to the jnp reduction otherwise.
+    and falls back to the jnp reduction for shapes that do not tile.
     """
     b, n = act.shape
-    if b % DEFAULT_BB == 0 and n % DEFAULT_BN == 0:
-        return apoz_counts_pallas(act).astype(jnp.float32) / b
+    bn = column_block(n)
+    if b % DEFAULT_BB == 0 and bn is not None:
+        return apoz_counts_pallas(act, bn=bn).astype(jnp.float32) / b
     return jnp.mean((act == 0.0).astype(jnp.float32), axis=0)
 
 
@@ -90,24 +121,11 @@ def apoz_batch_fractions(params, xb, neuron_masks=None):
 def apoz_scorer_compile_count() -> int:
     """Compiled-variant count of the batched APoZ scorer (jit cache).
 
-    Same ``_cache_size`` introspection caveat as
-    ``repro.fed.engine.scbf_compile_count``: not public API, pinned to
-    the CI jax version.
+    Reads jit's ``_cache_size`` hook, like
+    ``repro.fed.engine.scbf_compile_count``.
     """
-    try:
-        return int(apoz_batch_fractions._cache_size())
-    except AttributeError as e:
-        raise RuntimeError(
-            "jit cache introspection (_cache_size) is unavailable on this "
-            "jax version; compile-count assertions need the pinned "
-            "jax==0.4.37 API or an equivalent hook") from e
+    return int(apoz_batch_fractions._cache_size())
 
 
 def reset_apoz_scorer_compile_count() -> None:
-    try:
-        apoz_batch_fractions._clear_cache()
-    except AttributeError as e:
-        raise RuntimeError(
-            "jit cache clearing (_clear_cache) is unavailable on this "
-            "jax version; compile-count assertions need the pinned "
-            "jax==0.4.37 API or an equivalent hook") from e
+    apoz_batch_fractions._clear_cache()

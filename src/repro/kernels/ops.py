@@ -1,25 +1,23 @@
 """jit'd public wrappers around the Pallas kernels.
 
-CPU runs use interpret=True (the kernel body executes in Python with
-numpy semantics — correctness validation); on TPU the same calls compile
-to Mosaic.  Inputs are padded up to block multiples here so the kernels
-themselves stay branch-free; padding is score-neutral (zeros contribute
-nothing to squared norms, padded entries are masked out of counts).
+Off the TPU, ``interpret=None`` runs the kernel body in Python with
+numpy semantics (correctness validation); on a TPU the same calls try
+to compile to Mosaic.  The choice is made per call
+(``apoz.default_interpret``), never at import.  Inputs are padded up
+to block multiples here so the kernels themselves stay branch-free;
+padding is score-neutral (zeros contribute nothing to squared norms,
+padded entries are masked out of counts).
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
 
-from repro.kernels.apoz import apoz_counts_pallas
+from repro.kernels.apoz import apoz_counts_pallas, default_interpret
 from repro.kernels.channel_norm import channel_norms_pallas
 from repro.kernels.select_mask import (select_compact_pallas,
                                        select_mask_pallas)
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 
 def _pad2(x, bm, bn, value=0.0):
@@ -34,7 +32,7 @@ def _pad2(x, bm, bn, value=0.0):
 def channel_norms(g: jnp.ndarray, bm: int = 256, bn: int = 256,
                   interpret: bool = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Row and column squared norms of g (M,N), fp32, via one fused pass."""
-    interpret = _INTERPRET if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     bm = min(bm, max(8, g.shape[0]))
     bn = min(bn, max(8, g.shape[1]))
     gp, m, n = _pad2(g, bm, bn)
@@ -46,7 +44,7 @@ def select_mask(g: jnp.ndarray, row: jnp.ndarray, col: jnp.ndarray,
                 threshold, bm: int = 256, bn: int = 256,
                 interpret: bool = None) -> jnp.ndarray:
     """Masked gradient g̃ (keep where row[i]+col[j] > threshold)."""
-    interpret = _INTERPRET if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     bm = min(bm, max(8, g.shape[0]))
     bn = min(bn, max(8, g.shape[1]))
     gp, m, n = _pad2(g, bm, bn)
@@ -64,7 +62,7 @@ def scbf_select_fused(g: jnp.ndarray, row: jnp.ndarray, col: jnp.ndarray,
                       threshold, bm: int = 256, bn: int = 256,
                       interpret: bool = None):
     """(masked g̃, kept-entry count) in one kernel launch."""
-    interpret = _INTERPRET if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     bm = min(bm, max(8, g.shape[0]))
     bn = min(bn, max(8, g.shape[1]))
     gp, m, n = _pad2(g, bm, bn)
@@ -117,7 +115,6 @@ def select_compact(g: jnp.ndarray, row: jnp.ndarray, col: jnp.ndarray,
 def apoz_counts(acts: jnp.ndarray, bb: int = 512, bn: int = 256,
                 interpret: bool = None) -> jnp.ndarray:
     """Zero counts per neuron over the batch; APoZ = counts / batch."""
-    interpret = _INTERPRET if interpret is None else interpret
     bb = min(bb, max(8, acts.shape[0]))
     bn = min(bn, max(8, acts.shape[1]))
     # pad batch rows with ones (non-zero → contribute no zero counts)

@@ -15,6 +15,19 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices):
+    """``jax.make_mesh`` with Auto axes.
+
+    ``make_mesh`` defaults to Explicit axes, under which
+    ``with_sharding_constraint`` and ``vmap(spmd_axis_name=...)`` cannot
+    name the mesh axes; every mesh here is an Auto-sharded one.
+    """
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,13 +40,13 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "the dry-run must set XLA_FLAGS=--xla_force_host_platform_"
             "device_count=512 before importing jax")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_host_mesh(shape=(1, 1), axes=("data", "model")):
     """Single-device mesh for CPU smoke tests of the sharded code path."""
     n = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _auto_mesh(shape, axes, jax.devices()[:n])
 
 
 def make_pod_mesh(num_pods: int):
@@ -52,4 +65,4 @@ def make_pod_mesh(num_pods: int):
             f"need {num_pods} devices for a pod mesh, have {len(devices)} — "
             "on CPU set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{num_pods} before importing jax")
-    return jax.make_mesh((num_pods,), ("pod",), devices=devices[:num_pods])
+    return _auto_mesh((num_pods,), ("pod",), devices[:num_pods])

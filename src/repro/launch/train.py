@@ -171,6 +171,8 @@ def run_lm(args):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["medical", "lm"], default="medical")
     ap.add_argument("--methods", default="scbf,fedavg,scbfwp,fedavgwp")
