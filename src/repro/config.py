@@ -373,17 +373,14 @@ class FedConfig:
 class ObsConfig:
     """Flight-recorder knobs (repro.obs, docs/OBSERVABILITY.md).
 
-    ``device_metrics`` forces on-device per-round telemetry (loss /
+    ``device_metrics`` turns on on-device per-round telemetry (loss /
     selected channels / wire bytes accumulated inside the engine
-    programs) even without an active recorder; with a recorder active
-    (``obs.trace.recording``) collection turns on automatically.
-    ``annotate`` wraps fused chunk dispatches in
-    ``jax.profiler.TraceAnnotation`` while recording, so device
-    profiles line up with the host event log.
+    programs).  It is the only switch for it: an active recorder
+    (``obs.trace.recording``) turns on the host event log alone, so a
+    recorded run compiles the same programs as an unrecorded one.
     """
 
     device_metrics: bool = False
-    annotate: bool = True
 
 
 @dataclass(frozen=True)

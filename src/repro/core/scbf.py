@@ -310,117 +310,127 @@ def run_federated(cohort: MedicalCohort,
             "would remap wrong — use deadline_action='drop' with "
             "pruning")
 
-    feats = mlp_features or (cohort.num_features, 256, 64, 1)
-    key = jax.random.PRNGKey(train_cfg.seed)
-    key, init_key = jax.random.split(key)
-    params = init_mlp(feats, init_key)
+    # job set-up, from the model's initialisation to the round loop, is a
+    # span of its own so that profiles name it; ``cohort_put`` inside it
+    # is the engine build with the cohort's copy to the device
+    with obstrace.span("job_setup"):
+        feats = mlp_features or (cohort.num_features, 256, 64, 1)
+        key = jax.random.PRNGKey(train_cfg.seed)
+        key, init_key = jax.random.split(key)
+        params = init_mlp(feats, init_key)
 
-    clients = _partition(cohort, train_cfg)
-    eng = make_engine(engine or fed.engine, clients,
-                      train_cfg.local_batch_size, train_cfg.local_epochs,
-                      bucket=fed.bucket, pods=fed.pods)
-    clock = SimClock(cfg.num_clients, fed.clock, seed=train_cfg.seed) \
-        if clock_on else None
-    scheduler = make_scheduler(fed, cfg.num_clients, train_cfg.seed,
-                               clock=clock)
-    injector = FaultInjector(cfg.num_clients, fed.faults) \
-        if faults_on else None
-    # the admission gate arms whenever payloads can be hostile (fault
-    # injection) or a norm bound is configured; otherwise the strategies
-    # keep their zero-overhead fault-free hot path
-    policy = AdmissionPolicy(max_update_norm=fed.max_update_norm,
-                             norm_action=fed.norm_action) \
-        if (faults_on or fed.max_update_norm > 0) else None
-    strategy = make_strategy(method, cfg, fed, policy=policy)
-    resil = Resilience(scheduler, clock, injector, fed)
-    if fed.min_valid_participants > 0 and \
-            fed.min_valid_participants > scheduler.max_participants:
-        raise ValueError(
-            f"min_valid_participants={fed.min_valid_participants} can "
-            f"never be met: the scheduler samples at most "
-            f"{scheduler.max_participants} clients per round — every "
-            "round would exhaust its retries and miss quorum")
-    state = strategy.init(params)
-    # fedbuff only: stale version snapshots (sync trains on the current
-    # params, so keeping the initial model alive would be pure waste)
-    history = {0: params} if fed.mode == "fedbuff" else None
-    # spill mode: round-keyed snapshots — a spilled client trains from
-    # the params of the round it was sampled in, delivered rounds later
-    round_history = {0: params} if spill_mode else None
-    # host-side lr table: one device dispatch for the whole run instead
-    # of a float() sync per loop, and the fused path's (S,) lr array
-    lrs = _lr_table(train_cfg)
-
-    if cfg.dp_noise_multiplier < 0:
-        raise ValueError(
-            f"dp_noise_multiplier must be >= 0, got "
-            f"{cfg.dp_noise_multiplier}: the DP gate is "
-            f"'dp_noise_multiplier > 0', so a negative value would "
-            f"silently run without DP while looking configured")
-    dp_on = method == "scbf" and cfg.dp_noise_multiplier > 0
-    if dp_on:
-        # fail fast on an unknown accountant or a classic-bound run
-        # outside its eps <= 1 domain, not after a full training loop
-        privacy.epsilon_for(cfg.dp_noise_multiplier, cfg.dp_delta,
-                            loops=1, accountant=cfg.dp_accountant)
-    amplify = dp_on and cfg.dp_amplification
-    amp_q = 1.0
-    if amplify:
-        if clock_on:
+        clients = _partition(cohort, train_cfg)
+        with obstrace.span("cohort_put"):
+            eng = make_engine(engine or fed.engine, clients,
+                              train_cfg.local_batch_size,
+                              train_cfg.local_epochs,
+                              bucket=fed.bucket, pods=fed.pods)
+            # the engine holds the cohort now: free the host shards here,
+            # inside set-up, not when the job returns (about 20 ms for the
+            # paper's 215 MB cohort on a v5e host)
+            del clients
+        clock = SimClock(cfg.num_clients, fed.clock, seed=train_cfg.seed) \
+            if clock_on else None
+        scheduler = make_scheduler(fed, cfg.num_clients, train_cfg.seed,
+                                   clock=clock)
+        injector = FaultInjector(cfg.num_clients, fed.faults) \
+            if faults_on else None
+        # the admission gate arms whenever payloads can be hostile (fault
+        # injection) or a norm bound is configured; otherwise the strategies
+        # keep their zero-overhead fault-free hot path
+        policy = AdmissionPolicy(max_update_norm=fed.max_update_norm,
+                                 norm_action=fed.norm_action) \
+            if (faults_on or fed.max_update_norm > 0) else None
+        strategy = make_strategy(method, cfg, fed, policy=policy)
+        resil = Resilience(scheduler, clock, injector, fed)
+        if fed.min_valid_participants > 0 and \
+                fed.min_valid_participants > scheduler.max_participants:
             raise ValueError(
-                "subsampled amplification assumes a uniform i.i.d. "
-                "per-round sample; the simulated clock restricts "
-                "sampling to currently-available clients (diurnal "
-                "churn), which is not one — refusing to report a "
-                "silently-wrong amplified ε")
-        if fed.mode == "fedbuff":
-            raise ValueError(
-                "subsampled amplification assumes an i.i.d. per-round "
-                "sample; fedbuff participation is not one — refusing to "
-                "report a silently-wrong amplified ε")
-        if cfg.dp_accountant != "rdp":
-            raise ValueError("dp_amplification is an RDP analysis; it "
-                             f"composes on the subsampled RDP curve, so "
-                             f"dp_accountant={cfg.dp_accountant!r} cannot "
-                             "back the reported ε — use 'rdp'")
-        # q from the scheduler's own cohort-size formula, so the
-        # reported amplification always matches the sampling performed
-        amp_q = min(1.0, scheduler.max_participants / cfg.num_clients)
-        privacy.amplified_epsilon_for(cfg.dp_noise_multiplier, amp_q,
-                                      cfg.dp_delta, rounds=1)  # fail fast
-    # ε composes per *release*, not per loop: under sampling, dropout or
-    # fedbuff a client uploads in only some rounds, so the spend is
-    # tracked per client and the worst (most-releasing) client reported.
-    # (The amplified curve instead composes over rounds — every round is
-    # one inclusion trial for every client.)
-    dp_releases = np.zeros(cfg.num_clients, dtype=np.int64)
-    pruner = None
-    if cfg.prune:
-        # fedbuff keeps full-geometry stale snapshots alive for its
-        # in-flight clients, so the one-shot mask-mode compaction must
-        # stay off there (mixed geometries could never stack)
-        pruner = pruning.Pruner(
-            params, cohort.x_val, prune_rate=cfg.prune_rate,
-            prune_total=cfg.prune_total, impl=cfg.prune_impl,
-            compact=cfg.prune_compact and fed.mode != "fedbuff")
-    result = RunResult(method=method + ("wp" if cfg.prune else ""),
-                       dp_delta=cfg.dp_delta if dp_on else None)
+                f"min_valid_participants={fed.min_valid_participants} can "
+                f"never be met: the scheduler samples at most "
+                f"{scheduler.max_participants} clients per round — every "
+                "round would exhaust its retries and miss quorum")
+        state = strategy.init(params)
+        # fedbuff only: stale version snapshots (sync trains on the current
+        # params, so keeping the initial model alive would be pure waste)
+        history = {0: params} if fed.mode == "fedbuff" else None
+        # spill mode: round-keyed snapshots — a spilled client trains from
+        # the params of the round it was sampled in, delivered rounds later
+        round_history = {0: params} if spill_mode else None
+        # host-side lr table: one device dispatch for the whole run instead
+        # of a float() sync per loop, and the fused path's (S,) lr array
+        lrs = _lr_table(train_cfg)
 
-    # ---- flight recorder (repro.obs, docs/OBSERVABILITY.md) ----
-    # device telemetry turns on under an active recorder or by explicit
-    # config; the compile-count watchdog only samples while recording
-    # (it touches jit caches, and un-recorded runs shouldn't)
-    collect = train_cfg.obs.device_metrics or \
-        obstrace.get_recorder() is not None
-    counts0 = _compile_counts() if obstrace.get_recorder() is not None \
-        else None
-    obstrace.event(
-        "run_start", method=result.method, loops=train_cfg.global_loops,
-        clients=cfg.num_clients, engine=eng.name,
-        fuse_rounds=int(fed.fuse_rounds), mode=fed.mode,
-        dp_sigma=(cfg.dp_noise_multiplier * cfg.dp_clip_norm)
-        if dp_on else None,
-        prune=cfg.prune, prune_impl=cfg.prune_impl if cfg.prune else None)
+        if cfg.dp_noise_multiplier < 0:
+            raise ValueError(
+                f"dp_noise_multiplier must be >= 0, got "
+                f"{cfg.dp_noise_multiplier}: the DP gate is "
+                f"'dp_noise_multiplier > 0', so a negative value would "
+                f"silently run without DP while looking configured")
+        dp_on = method == "scbf" and cfg.dp_noise_multiplier > 0
+        if dp_on:
+            # fail fast on an unknown accountant or a classic-bound run
+            # outside its eps <= 1 domain, not after a full training loop
+            privacy.epsilon_for(cfg.dp_noise_multiplier, cfg.dp_delta,
+                                loops=1, accountant=cfg.dp_accountant)
+        amplify = dp_on and cfg.dp_amplification
+        amp_q = 1.0
+        if amplify:
+            if clock_on:
+                raise ValueError(
+                    "subsampled amplification assumes a uniform i.i.d. "
+                    "per-round sample; the simulated clock restricts "
+                    "sampling to currently-available clients (diurnal "
+                    "churn), which is not one — refusing to report a "
+                    "silently-wrong amplified ε")
+            if fed.mode == "fedbuff":
+                raise ValueError(
+                    "subsampled amplification assumes an i.i.d. per-round "
+                    "sample; fedbuff participation is not one — refusing to "
+                    "report a silently-wrong amplified ε")
+            if cfg.dp_accountant != "rdp":
+                raise ValueError("dp_amplification is an RDP analysis; it "
+                                 f"composes on the subsampled RDP curve, so "
+                                 f"dp_accountant={cfg.dp_accountant!r} cannot "
+                                 "back the reported ε — use 'rdp'")
+            # q from the scheduler's own cohort-size formula, so the
+            # reported amplification always matches the sampling performed
+            amp_q = min(1.0, scheduler.max_participants / cfg.num_clients)
+            privacy.amplified_epsilon_for(cfg.dp_noise_multiplier, amp_q,
+                                          cfg.dp_delta, rounds=1)  # fail fast
+        # ε composes per *release*, not per loop: under sampling, dropout or
+        # fedbuff a client uploads in only some rounds, so the spend is
+        # tracked per client and the worst (most-releasing) client reported.
+        # (The amplified curve instead composes over rounds — every round is
+        # one inclusion trial for every client.)
+        dp_releases = np.zeros(cfg.num_clients, dtype=np.int64)
+        pruner = None
+        if cfg.prune:
+            # fedbuff keeps full-geometry stale snapshots alive for its
+            # in-flight clients, so the one-shot mask-mode compaction must
+            # stay off there (mixed geometries could never stack)
+            pruner = pruning.Pruner(
+                params, cohort.x_val, prune_rate=cfg.prune_rate,
+                prune_total=cfg.prune_total, impl=cfg.prune_impl,
+                compact=cfg.prune_compact and fed.mode != "fedbuff")
+        result = RunResult(method=method + ("wp" if cfg.prune else ""),
+                           dp_delta=cfg.dp_delta if dp_on else None)
+
+        # ---- flight recorder (repro.obs, docs/OBSERVABILITY.md) ----
+        # device telemetry is the config's switch alone, so a recorded run
+        # compiles the programs an unrecorded one does; the compile-count
+        # watchdog only samples while recording (it touches jit caches,
+        # and un-recorded runs shouldn't)
+        collect = train_cfg.obs.device_metrics
+        counts0 = _compile_counts() if obstrace.get_recorder() is not None \
+            else None
+        obstrace.event(
+            "run_start", method=result.method, loops=train_cfg.global_loops,
+            clients=cfg.num_clients, engine=eng.name,
+            fuse_rounds=int(fed.fuse_rounds), mode=fed.mode,
+            dp_sigma=(cfg.dp_noise_multiplier * cfg.dp_clip_norm)
+            if dp_on else None,
+            prune=cfg.prune, prune_impl=cfg.prune_impl if cfg.prune else None)
 
     def _epsilons(loop: int):
         """(epsilon, epsilon_unamplified) for the record of ``loop``."""
@@ -795,51 +805,49 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
         prune_active = pruner is not None and pruner.active
         chunk = fused_chunk_len(total_loops - loop0, S, prune_active)
         # the chunk span replaces the hand-rolled perf_counter pair: it
-        # covers plan → keys → chunk dispatch → emit → prune, and (while
-        # recording) annotates the region in device profiles so
-        # jax.profiler traces line up with the event log
-        with obstrace.span("fused_chunk", annotate=train_cfg.obs.annotate,
-                           loop0=loop0, rounds=chunk) as sp:
-            # the resilient planner replaces plan_horizon: same
-            # scheduler.plan sequence underneath (bit-parity when the
-            # fault model is off), plus fault outcomes and quorum
-            # resolved per round at plan time — which is what lets the
-            # admission verdicts fold into the static (S, B) admit mask
-            ars = [resil.plan_round(loop0 + i, state.version)
-                   for i in range(chunk)]
-            plans = [ar.plan for ar in ars]
-            parts, cks, sks, dks, wts = [], [], [], [], []
-            for ar, plan in zip(ars, plans):
-                part = plan.participants
-                P = plan.num_participants
-                # _derive_round_keys is the single key-stream contract,
-                # so the fused pre-planner consumes EXACTLY what the
-                # per-round loop would have
-                key, ck, sk, dk = _derive_round_keys(key, cfg.num_clients,
-                                                     part, P)
-                cks.append(np.asarray(ck))
-                sks.append(np.asarray(sk))
-                dks.append(np.asarray(dk))
-                parts.append(part)
-                if method == "fedavg":
-                    if P and ar.quorum_ok:
-                        n = eng.counts[np.asarray(part)].astype(np.float64)
-                        wts.append((n / n.sum()).astype(np.float32))
-                    else:
-                        # quorum-missed rounds must not step: all-zero
-                        # weights pass the fedavg carry through bitwise
-                        wts.append(np.zeros(P, np.float32))
-            keep_eff = pruner.emission_keep if pruner is not None else None
-            eff = obsm.effective_leaf_sizes(state.params, keep_eff) \
-                if (collect and method == "scbf" and keep_eff is not None) \
-                else None
-            admits = [ar.admit_mask() for ar in ars] if resil.active \
-                else None
-            fplan = eng.prepare_fused_plan(
-                parts, lrs[loop0:loop0 + chunk], cks, sks, dks,
-                horizon=1 if prune_active else S, num_slots=B,
-                weights=wts if method == "fedavg" else None,
-                eff_sizes=eff, admit=admits)
+        # covers plan → keys → chunk dispatch → emit → prune
+        with obstrace.span("fused_chunk", loop0=loop0, rounds=chunk) as sp:
+            with obstrace.span("plan", rounds=chunk):
+                # the resilient planner replaces plan_horizon: same
+                # scheduler.plan sequence underneath (bit-parity when the
+                # fault model is off), plus fault outcomes and quorum
+                # resolved per round at plan time — which is what lets the
+                # admission verdicts fold into the static (S, B) admit mask
+                ars = [resil.plan_round(loop0 + i, state.version)
+                       for i in range(chunk)]
+                plans = [ar.plan for ar in ars]
+                parts, cks, sks, dks, wts = [], [], [], [], []
+                for ar, plan in zip(ars, plans):
+                    part = plan.participants
+                    P = plan.num_participants
+                    # _derive_round_keys is the single key-stream contract,
+                    # so the fused pre-planner consumes EXACTLY what the
+                    # per-round loop would have
+                    key, ck, sk, dk = _derive_round_keys(key, cfg.num_clients,
+                                                         part, P)
+                    cks.append(np.asarray(ck))
+                    sks.append(np.asarray(sk))
+                    dks.append(np.asarray(dk))
+                    parts.append(part)
+                    if method == "fedavg":
+                        if P and ar.quorum_ok:
+                            n = eng.counts[np.asarray(part)].astype(np.float64)
+                            wts.append((n / n.sum()).astype(np.float32))
+                        else:
+                            # quorum-missed rounds must not step: all-zero
+                            # weights pass the fedavg carry through bitwise
+                            wts.append(np.zeros(P, np.float32))
+                keep_eff = pruner.emission_keep if pruner is not None else None
+                eff = obsm.effective_leaf_sizes(state.params, keep_eff) \
+                    if (collect and method == "scbf"
+                        and keep_eff is not None) else None
+                admits = [ar.admit_mask() for ar in ars] if resil.active \
+                    else None
+                fplan = eng.prepare_fused_plan(
+                    parts, lrs[loop0:loop0 + chunk], cks, sks, dks,
+                    horizon=1 if prune_active else S, num_slots=B,
+                    weights=wts if method == "fedavg" else None,
+                    eff_sizes=eff, admit=admits)
             round_metrics = None
             if method == "scbf":
                 out = eng.fused_scbf_chunk(
@@ -893,95 +901,99 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
                                    hidden=list(pruner.hidden_sizes()))
         wall_each = sp.elapsed / chunk
 
-        n_params, hidden = _model_stats()
-        for r, (ar, plan) in enumerate(zip(ars, plans)):
-            loop = loop0 + r
-            P = plan.num_participants
-            payloads, stats = emitted[r]
-            dm = round_metrics[r] if round_metrics is not None else None
-            if method == "scbf":
-                # aborted quorum attempts are distinct uploads (fresh
-                # keys each attempt): two increments = two releases
-                for aborted in ar.aborted_arrivers:
-                    if aborted.size:
-                        dp_releases[np.asarray(aborted)] += 1  # privlint: disable=PL004
-                wire_payloads = payloads
-                if injector is not None and payloads:
-                    # re-run the fault pipeline + the REAL admission
-                    # gate on the emitted wire artifacts: events/counts
-                    # match the per-round path, and the verdicts are
-                    # checked against the plan the device already
-                    # folded in (any divergence is a hard error, never
-                    # a silent one)
-                    cl = np.asarray(plan.participants)
-                    wire_payloads, dup_src = apply_payload_faults(
-                        payloads, cl, ar.corrupt, ar.duplicated, loop,
-                        ar.attempts - 1, fed.faults, fed.max_update_norm)
-                    if ar.quorum_ok:
-                        if dup_src:
-                            cl = np.concatenate([cl, cl[dup_src]])
-                        gate_contrib = RoundContribution(
-                            num_examples=np.zeros(len(wire_payloads),
-                                                  np.int64),
-                            staleness=np.zeros(len(wire_payloads),
-                                               np.int64),
-                            payloads=wire_payloads, clients=cl)
-                        _, kept_idx = admit_payloads(state, gate_contrib,
-                                                     policy)
-                        planned = {i for i in range(P)
-                                   if not ar.will_reject[i]}
-                        if set(kept_idx) != planned:
-                            raise RuntimeError(
-                                f"fused admission mismatch at loop "
-                                f"{loop}: the device folded slots "
-                                f"{sorted(planned)} but the admission "
-                                f"gate admitted {sorted(kept_idx)} — "
-                                "an update failed a gate the planner "
-                                "could not predict (e.g. a natural "
-                                "nonfinite or norm violation); rerun "
-                                "with fuse_rounds=1")
-                up_frac = float(np.mean([s.upload_fraction
-                                         for s in stats])) if stats else 0.0
-                sparse_bytes = int(np.sum([p.nbytes
-                                           for p in wire_payloads])) \
-                    if wire_payloads else 0
-                dense_bytes = int(np.sum([p.dense_nbytes
-                                          for p in payloads])) \
-                    if payloads else 0
-                if P:
-                    dp_releases[np.asarray(plan.participants)] += 1
-            else:
-                up_frac = 1.0 if P else 0.0
-                dense_bytes = n_params * 4 * P
-                sparse_bytes = dense_bytes
-            do_eval = (r == chunk - 1) and _should_eval(
-                loop, total_loops, train_cfg.eval_every)
-            roc, pr, evaluated = _metrics(
-                state.params, do_eval,
-                pruner.masks if pruner is not None else None)
-            eps, eps_un = _epsilons(loop)
-            rec = LoopRecord(
-                loop=loop, auc_roc=roc, auc_pr=pr,
-                upload_fraction=up_frac,
-                sparse_bytes=sparse_bytes, dense_bytes=dense_bytes,
-                wall_time=wall_each,
-                flops_proxy=float(n_params) * cohort.x_train.shape[0],
-                hidden_sizes=hidden, num_participants=P,
-                epsilon=eps, evaluated=evaluated,
-                epsilon_unamplified=eps_un,
-                train_loss=(dm or {}).get("train_loss")
-                if (dm and P) else None,
-                wall_is_amortized=True)
-            result.records.append(rec)
-            obstrace.event("round", **_round_event_fields(
-                rec, plan, pruner, dm if P else None,
-                eps_step=(eps - prev_eps) if eps is not None else None))
-            prev_eps = eps if eps is not None else 0.0
-            if verbose:
-                print(f"[{result.method}] loop {loop:02d} "
-                      f"auc_roc={roc:.4f} auc_pr={pr:.4f} "
-                      f"upload={up_frac:.2%} hidden={rec.hidden_sizes} "
-                      f"clients={P} t={wall_each:.2f}s"
-                      + ("" if evaluated else " (metrics carried)"))
+        # the chunk's per-round records; the chunk-boundary ``eval`` nests
+        # inside
+        with obstrace.span("records", loop0=loop0, rounds=chunk):
+            n_params, hidden = _model_stats()
+            for r, (ar, plan) in enumerate(zip(ars, plans)):
+                loop = loop0 + r
+                P = plan.num_participants
+                payloads, stats = emitted[r]
+                dm = round_metrics[r] if round_metrics is not None else None
+                if method == "scbf":
+                    # aborted quorum attempts are distinct uploads (fresh
+                    # keys each attempt): two increments = two releases
+                    for aborted in ar.aborted_arrivers:
+                        if aborted.size:
+                            dp_releases[np.asarray(aborted)] += 1  # privlint: disable=PL004
+                    wire_payloads = payloads
+                    if injector is not None and payloads:
+                        # re-run the fault pipeline + the REAL admission
+                        # gate on the emitted wire artifacts: events/counts
+                        # match the per-round path, and the verdicts are
+                        # checked against the plan the device already
+                        # folded in (any divergence is a hard error, never
+                        # a silent one)
+                        cl = np.asarray(plan.participants)
+                        wire_payloads, dup_src = apply_payload_faults(
+                            payloads, cl, ar.corrupt, ar.duplicated, loop,
+                            ar.attempts - 1, fed.faults, fed.max_update_norm)
+                        if ar.quorum_ok:
+                            if dup_src:
+                                cl = np.concatenate([cl, cl[dup_src]])
+                            gate_contrib = RoundContribution(
+                                num_examples=np.zeros(len(wire_payloads),
+                                                      np.int64),
+                                staleness=np.zeros(len(wire_payloads),
+                                                   np.int64),
+                                payloads=wire_payloads, clients=cl)
+                            _, kept_idx = admit_payloads(state, gate_contrib,
+                                                         policy)
+                            planned = {i for i in range(P)
+                                       if not ar.will_reject[i]}
+                            if set(kept_idx) != planned:
+                                raise RuntimeError(
+                                    f"fused admission mismatch at loop "
+                                    f"{loop}: the device folded slots "
+                                    f"{sorted(planned)} but the admission "
+                                    f"gate admitted {sorted(kept_idx)} — "
+                                    "an update failed a gate the planner "
+                                    "could not predict (e.g. a natural "
+                                    "nonfinite or norm violation); rerun "
+                                    "with fuse_rounds=1")
+                    up_frac = float(np.mean(
+                        [s.upload_fraction for s in stats])) \
+                        if stats else 0.0
+                    sparse_bytes = int(np.sum([p.nbytes
+                                               for p in wire_payloads])) \
+                        if wire_payloads else 0
+                    dense_bytes = int(np.sum([p.dense_nbytes
+                                              for p in payloads])) \
+                        if payloads else 0
+                    if P:
+                        dp_releases[np.asarray(plan.participants)] += 1
+                else:
+                    up_frac = 1.0 if P else 0.0
+                    dense_bytes = n_params * 4 * P
+                    sparse_bytes = dense_bytes
+                do_eval = (r == chunk - 1) and _should_eval(
+                    loop, total_loops, train_cfg.eval_every)
+                roc, pr, evaluated = _metrics(
+                    state.params, do_eval,
+                    pruner.masks if pruner is not None else None)
+                eps, eps_un = _epsilons(loop)
+                rec = LoopRecord(
+                    loop=loop, auc_roc=roc, auc_pr=pr,
+                    upload_fraction=up_frac,
+                    sparse_bytes=sparse_bytes, dense_bytes=dense_bytes,
+                    wall_time=wall_each,
+                    flops_proxy=float(n_params) * cohort.x_train.shape[0],
+                    hidden_sizes=hidden, num_participants=P,
+                    epsilon=eps, evaluated=evaluated,
+                    epsilon_unamplified=eps_un,
+                    train_loss=(dm or {}).get("train_loss")
+                    if (dm and P) else None,
+                    wall_is_amortized=True)
+                result.records.append(rec)
+                obstrace.event("round", **_round_event_fields(
+                    rec, plan, pruner, dm if P else None,
+                    eps_step=(eps - prev_eps) if eps is not None else None))
+                prev_eps = eps if eps is not None else 0.0
+                if verbose:
+                    print(f"[{result.method}] loop {loop:02d} "
+                          f"auc_roc={roc:.4f} auc_pr={pr:.4f} "
+                          f"upload={up_frac:.2%} hidden={rec.hidden_sizes} "
+                          f"clients={P} t={wall_each:.2f}s"
+                          + ("" if evaluated else " (metrics carried)"))
         loop0 += chunk
     result.final_params = state.params

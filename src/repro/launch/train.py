@@ -49,7 +49,7 @@ import contextlib
 
 def run_medical(args):
     import jax
-    from repro.config import FedConfig, ScbfConfig, TrainConfig
+    from repro.config import FedConfig, ObsConfig, ScbfConfig, TrainConfig
     from repro.core.scbf import run_federated
     from repro.data.medical import generate_cohort
     from repro.obs import recording
@@ -107,7 +107,10 @@ def run_medical(args):
                                                "reshape"),
                             dp_noise_multiplier=getattr(
                                 args, "dp_noise", 0.0)),
-            fed=fed)
+            fed=fed,
+            # the event log's per-round train_loss comes from the device
+            # metrics, which only this switch turns on
+            obs=ObsConfig(device_metrics=getattr(args, "events", False)))
         # --events: one flight-recorder JSONL per method, feed it to
         # ``python -m repro.obs.report`` (docs/OBSERVABILITY.md)
         rec_ctx = recording(os.path.join(args.out, f"{method}.events.jsonl")) \

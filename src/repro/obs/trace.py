@@ -27,9 +27,10 @@ Three pieces:
     ``perf_counter`` calls) so callers can use the span as their one
     wall-clock source whether or not a recorder is active — this is
     what replaced the hand-rolled timing blocks in ``core/scbf.py``.
-    With ``annotate=True`` and an active recorder the region is also
-    wrapped in ``jax.profiler.TraceAnnotation`` so device profiles
-    (``jax.profiler.trace``) show the same names as the event log.
+    While a recorder is active every span is also a
+    ``jax.profiler.TraceAnnotation`` of the same name, so a device
+    profile (``jax.profiler.trace``) holds every span on its own clock,
+    next to the device ops.
 
 Everything here is host-only code: no jax arrays are touched, so the
 module is trivially TL002/TL006-clean (docs/STATIC_ANALYSIS.md).
@@ -166,14 +167,13 @@ def count(name: str, n: int = 1) -> None:
 
 
 @contextlib.contextmanager
-def span(name: str, annotate: bool = False, **attrs):
+def span(name: str, **attrs):
     """Timed region: always measures, records when a recorder is active.
 
-    ``annotate=True`` additionally wraps the region in
-    ``jax.profiler.TraceAnnotation`` (recorder active only, so the
-    default un-recorded path stays free of any jax call) — the fused
-    chunk dispatches carry this so device profiles line up with the
-    event log.
+    While recording, the region is also a ``jax.profiler.TraceAnnotation``
+    of the same name, so profiles carry every span on the profiler's
+    clock.  Without a recorder the span is two ``perf_counter`` calls
+    and makes no jax call.
     """
     rec = get_recorder()
     if rec is None:
@@ -183,12 +183,8 @@ def span(name: str, annotate: bool = False, **attrs):
         finally:
             sp.stop()
         return
-    if annotate:
-        import jax.profiler
-        with jax.profiler.TraceAnnotation(name):
-            with rec.span(name, **attrs) as sp:
-                yield sp
-    else:
+    import jax.profiler
+    with jax.profiler.TraceAnnotation(name):
         with rec.span(name, **attrs) as sp:
             yield sp
 

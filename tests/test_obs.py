@@ -307,9 +307,12 @@ REQUIRED_FIELDS = {
 @pytest.fixture(scope="module")
 def golden_run(cohort, tmp_path_factory):
     path = str(tmp_path_factory.mktemp("obs") / "events.jsonl")
+    # device metrics are the config's switch: the recorder alone keeps
+    # the host event log only
+    obs = ObsConfig(device_metrics=True)
     with recording(path):
-        res = run_federated(cohort, _tcfg(2, loops=4), method="scbf",
-                            mlp_features=FEATS)
+        res = run_federated(cohort, _tcfg(2, loops=4, obs=obs),
+                            method="scbf", mlp_features=FEATS)
     return path, res
 
 
@@ -341,6 +344,117 @@ def test_recording_off_leaves_no_telemetry(cohort):
     res = run_federated(cohort, _tcfg(2, loops=2), method="scbf",
                         mlp_features=FEATS)
     assert res.telemetry is None
+
+
+# ---------------------------------------------------------------------------
+# host spans: every stretch of a job named, on the profiler's clock
+# ---------------------------------------------------------------------------
+
+# rounding of the event log (ts and dur to the microsecond)
+_TOL = 3e-6
+
+
+def _span_intervals(events):
+    """{name: [(start_s, end_s, event), ...]} of a recorder's spans."""
+    out = {}
+    for e in events:
+        if e["ev"] == "span":
+            out.setdefault(e["name"], []).append(
+                (e["ts"] - e["dur"], e["ts"], e))
+    return out
+
+
+def _inside(inner, outer):
+    return outer[0] - _TOL <= inner[0] and inner[1] <= outer[1] + _TOL
+
+
+def test_recorded_fused_job_names_every_host_stretch(cohort):
+    """Job set-up (with the cohort's copy), each chunk's planning, the
+    three parts of wire emission and the per-round records are spans,
+    nested as the layers are: wait, pull and encoding lie inside their
+    ``encode`` and add up to no more than it."""
+    rec = Recorder()
+    with recording(recorder=rec):
+        run_federated(cohort, _tcfg(2, loops=4), method="scbf",
+                      mlp_features=FEATS)
+    sp = _span_intervals(rec.events)
+    assert {"job_setup", "cohort_put", "plan", "emit_wait", "emit_pull",
+            "wire_encode", "records", "encode", "fused_chunk",
+            "eval"} <= set(sp)
+    (setup,), (put,) = sp["job_setup"], sp["cohort_put"]
+    assert _inside(put, setup)
+    chunks = sp["fused_chunk"]
+    assert len(chunks) == len(sp["plan"]) == len(sp["encode"]) \
+        == len(sp["records"]) == 2
+    assert setup[1] <= chunks[0][0] + _TOL
+    for chunk, plan, enc, recs in zip(chunks, sp["plan"], sp["encode"],
+                                      sp["records"]):
+        assert _inside(plan, chunk) and _inside(enc, chunk)
+        assert plan[1] <= enc[0] + _TOL
+        # the records follow their chunk, outside it
+        assert chunk[1] <= recs[0] + _TOL
+        parts = [next(p for p in sp[name] if _inside(p, enc))
+                 for name in ("emit_wait", "emit_pull", "wire_encode")]
+        for a, b in zip(parts, parts[1:]):
+            assert a[1] <= b[0] + _TOL
+        assert sum(p[2]["dur"] for p in parts) <= enc[2]["dur"] + _TOL
+        assert parts[1][2]["bytes"] > 0
+        assert parts[2][2]["slots"] == 2 * 5
+        # the chunk boundary's evaluation is nested in the records
+        assert any(_inside(ev, recs) for ev in sp["eval"])
+    for name in ("emit_wait", "emit_pull", "wire_encode"):
+        assert len(sp[name]) == 2
+
+
+def test_recorder_alone_runs_the_timed_program(cohort):
+    """An active recorder turns on the host event log and nothing else:
+    the recorded job is bitwise the unrecorded one, collects no device
+    metrics, and compiles no fused program the unrecorded job did not
+    already compile."""
+    cfg = _tcfg(4, loops=4)
+    plain = run_federated(cohort, cfg, method="scbf", mlp_features=FEATS)
+    with recording(recorder=Recorder()):
+        recd = run_federated(cohort, cfg, method="scbf",
+                             mlp_features=FEATS)
+    assert _params_bitwise_equal(plain.final_params, recd.final_params)
+    assert [r.sparse_bytes for r in plain.records] == \
+        [r.sparse_bytes for r in recd.records]
+    assert all(r.train_loss is None for r in recd.records)
+    assert recd.telemetry["fused_compiles"] == 0
+    assert recd.telemetry["host_offloads"] == 0
+
+
+def test_every_span_on_the_profiler_clock(cohort, tmp_path):
+    """While recording, every span is a profiler host event of the same
+    name: after aligning the recorder's clock on ``fused_chunk`` (the
+    benchmark's ``bench.tracereduce.align``), each span starts within
+    1 ms of its profiler event."""
+    import sys
+    from pathlib import Path
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import tracereduce
+
+    cfg = _tcfg(2, loops=4)
+    run_federated(cohort, cfg, method="scbf", mlp_features=FEATS)  # warm
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    rec = Recorder()
+    with recording(recorder=rec):
+        with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+            run_federated(cohort, cfg, method="scbf", mlp_features=FEATS)
+    trace = tracereduce.load_dir(str(tmp_path))
+    starts = {name: sorted(s * 1e9 for s, _, _ in iv)
+              for name, iv in _span_intervals(rec.events).items()}
+    prof = {name: [s for s, _ in tracereduce.host_events(trace, name)]
+            for name in starts}
+    off = tracereduce.align(starts["fused_chunk"], prof["fused_chunk"])
+    assert off is not None
+    for name, ours in starts.items():
+        assert len(prof[name]) == len(ours), name
+        for r, p in zip(ours, prof[name]):
+            assert abs(r + off - p) < 1e6, (name, (r + off - p) / 1e6)
 
 
 # ---------------------------------------------------------------------------
