@@ -154,24 +154,32 @@ class Payload:
 
 
 def encode_leaf(leaf, codec: str = "auto") -> LayerPayload:
-    """Encode one masked array; zeros are treated as masked-out."""
+    """Encode one masked array; zeros are treated as masked-out.
+
+    One boolean pass picks the kept entries and their count, and the
+    codec is chosen before any buffer is built: ``bitmap`` packs that
+    boolean and gathers through it (``np.compress``, about twice as fast
+    as boolean indexing), ``dense`` copies the leaf, and only ``coo``
+    builds an index array.
+    """
     a = np.asarray(leaf)
     flat = a.reshape(-1)
-    nz = np.flatnonzero(flat).astype(np.int32)
-    nnz, size, itemsize = int(nz.size), int(flat.size), flat.dtype.itemsize
+    kept = flat != 0
+    nnz, size, itemsize = (int(np.count_nonzero(kept)), int(flat.size),
+                           flat.dtype.itemsize)
     if codec == "auto":
         codec, nbytes = cheapest_bytes(nnz, size, itemsize)
     else:
         nbytes = codec_bytes(codec, nnz, size, itemsize)
     if codec == "coo":
+        idx = np.flatnonzero(kept).astype(np.int32)
         return LayerPayload(codec, a.shape, flat.dtype, nnz, nbytes,
-                            idx=nz, bitmap=None, values=flat[nz].copy())
+                            idx=idx, bitmap=None, values=flat[idx])
     if codec == "bitmap":
-        mask = np.zeros(size, np.uint8)
-        mask[nz] = 1
         return LayerPayload(codec, a.shape, flat.dtype, nnz, nbytes,
-                            idx=None, bitmap=np.packbits(mask),
-                            values=flat[nz].copy())
+                            idx=None, bitmap=np.packbits(kept),
+                            values=np.compress(kept, flat))
+    # copied: flat may be a view into the caller's array (a pulled stack)
     return LayerPayload(codec, a.shape, flat.dtype, size, nbytes,
                         idx=None, bitmap=None, values=flat.copy())
 

@@ -43,7 +43,7 @@ class UploadStats:
                 # host numpy on purpose: masks arrive per client, and the
                 # batched engine calls this K times per round — a device
                 # reduction per mask would serialise the host loop
-                nnz, size = int(np.sum(np.asarray(v))), int(v.size)
+                nnz, size = int(np.count_nonzero(np.asarray(v))), int(v.size)
                 up += nnz
                 total += size
                 sparse += wire.cheapest_bytes(nnz, size, itemsize=4)[1]

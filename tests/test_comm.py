@@ -128,3 +128,133 @@ def test_kernel_compact_buffers_match_wire_coo():
     np.testing.assert_array_equal(np.asarray(idx[:n]), lp.idx)
     np.testing.assert_allclose(np.asarray(vals[:n]),
                                lp.values.astype(np.float32), rtol=1e-6)
+
+
+def _encode_leaf_index_based(leaf, codec="auto"):
+    """Frozen reference: the index-array encoder the one-pass
+    ``wire.encode_leaf`` replaced (flat indices for every codec, a
+    scattered uint8 mask for the bitmap)."""
+    a = np.asarray(leaf)
+    flat = a.reshape(-1)
+    nz = np.flatnonzero(flat).astype(np.int32)
+    nnz, size, itemsize = int(nz.size), int(flat.size), flat.dtype.itemsize
+    if codec == "auto":
+        codec, nbytes = wire.cheapest_bytes(nnz, size, itemsize)
+    else:
+        nbytes = wire.codec_bytes(codec, nnz, size, itemsize)
+    if codec == "coo":
+        return wire.LayerPayload(codec, a.shape, flat.dtype, nnz, nbytes,
+                                 idx=nz, bitmap=None, values=flat[nz].copy())
+    if codec == "bitmap":
+        mask = np.zeros(size, np.uint8)
+        mask[nz] = 1
+        return wire.LayerPayload(codec, a.shape, flat.dtype, nnz, nbytes,
+                                 idx=None, bitmap=np.packbits(mask),
+                                 values=flat[nz].copy())
+    return wire.LayerPayload(codec, a.shape, flat.dtype, size, nbytes,
+                             idx=None, bitmap=None, values=flat.copy())
+
+
+# auto crossovers for 4-byte values: coo/bitmap at 1/32 kept,
+# bitmap/dense at 31/32
+IDENTITY_DENSITIES = [0.0, 0.02, 0.045, 0.5, 0.95, 0.99, 1.0]
+# 0-d, 1-D and 2-D sizes off a multiple of 8, and one 2-D on it
+IDENTITY_SHAPES = [(), (1001,), (33, 257), (16, 64)]
+# (dtype, with -0.0 among the dropped entries and a NaN among the kept)
+IDENTITY_KINDS = [("float32", False), ("float32", True),
+                  ("bfloat16", False), ("bfloat16", True), ("int32", False)]
+
+
+def _identity_leaf(shape, density, dtype, specials, seed=0):
+    """A leaf with exactly round(density * size) kept entries, returned
+    as a view into a stack (as the emitters pass pulled chunk slots)."""
+    rng = np.random.default_rng(seed)
+    size = int(np.prod(shape, dtype=np.int64))
+    dt = jnp.dtype(dtype)
+    if dt.kind == "i":
+        vals = rng.integers(1, 1000, size) * rng.choice([-1, 1], size)
+    else:
+        vals = rng.normal(size=size) + np.where(rng.random(size) < .5, 1, -1)
+    flat = vals.astype(dt)
+    assert np.count_nonzero(flat) == size
+    order = rng.permutation(size)
+    nkeep = int(round(density * size))
+    flat[order[nkeep:]] = 0
+    if specials:
+        flat[order[nkeep:][::2]] = -0.0
+        if nkeep:
+            flat[order[0]] = np.nan
+    stack = np.stack([np.zeros_like(flat), flat]).reshape((2,) + shape)
+    return stack[1]
+
+
+def _assert_same_payload(got, want):
+    assert got.codec == want.codec
+    assert got.nnz == want.nnz
+    assert got.nbytes == want.nbytes
+    assert tuple(got.shape) == tuple(want.shape)
+    assert np.dtype(got.dtype) == np.dtype(want.dtype)
+    for name in ("idx", "bitmap", "values"):
+        g, w = getattr(got, name), getattr(want, name)
+        if w is None:
+            assert g is None, name
+            continue
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert g.tobytes() == w.tobytes(), name
+    treedef = jax.tree_util.tree_structure([0])
+    assert (wire.payload_checksum(wire.Payload(treedef, (got,)))
+            == wire.payload_checksum(wire.Payload(treedef, (want,))))
+
+
+@pytest.mark.parametrize("kind", IDENTITY_KINDS,
+                         ids=lambda k: k[0] + ("-negzero-nan" * k[1]))
+@pytest.mark.parametrize("shape", IDENTITY_SHAPES, ids=str)
+@pytest.mark.parametrize("density", IDENTITY_DENSITIES)
+def test_encode_leaf_byte_identical_to_index_reference(kind, shape, density):
+    """The one-pass encoder ships exactly what the index-based encoder
+    shipped: codec, counts, buffers and checksum, for every codec forced
+    and for the cheapest one; no payload buffer aliases the input."""
+    dtype, specials = kind
+    leaf = _identity_leaf(shape, density, dtype, specials)
+    for codec in ("auto",) + wire.CODECS:
+        got = wire.encode_leaf(leaf, codec)
+        _assert_same_payload(got, _encode_leaf_index_based(leaf, codec))
+        assert not np.shares_memory(got.values, leaf)
+
+
+def test_identity_cases_reach_every_auto_codec():
+    """The densities of the identity test straddle both crossovers
+    (bitmap/dense lies at 15/16 kept for 2-byte values)."""
+    want = {"float32": ["coo", "coo", "bitmap", "bitmap", "bitmap",
+                        "dense", "dense"],
+            "bfloat16": ["coo", "coo", "bitmap", "bitmap", "dense",
+                         "dense", "dense"]}
+    for dtype, codecs in want.items():
+        picked = [wire.encode_leaf(
+            _identity_leaf((33, 257), d, dtype, False)).codec
+            for d in IDENTITY_DENSITIES]
+        assert picked == codecs
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_upload_stats_from_masks_counts_like_sum(seed):
+    rng = np.random.default_rng(seed)
+    masks = []
+    for _ in range(4):
+        layers = {}
+        for name, shape in (("w0", (37, 16)), ("w1", (16, 9)), ("w2", (9,))):
+            layers[name] = rng.random(shape) < rng.random()
+        layers["b0"] = None
+        layers["b1"] = rng.random((16,)) < 0.5
+        masks.append(layers)
+    st = selection.UploadStats.from_masks(masks)
+    up = total = sparse = 0
+    for m in masks:
+        for v in m.values():
+            if v is None:
+                continue
+            nnz = int(np.sum(v))
+            up, total = up + nnz, total + v.size
+            sparse += wire.cheapest_bytes(nnz, v.size, itemsize=4)[1]
+    assert (st.uploaded_params, st.total_params, st.sparse_bytes) == (
+        up, total, sparse)
