@@ -6,18 +6,18 @@ For each seed, in one process: one job of the program (the timed path,
 warm after the first seed's compile), and the cell's compared numbers
 for
 
-  program      the program against the float32 reference at HIGHEST
-  control      the reference computed in bfloat16, in the program's place
-  witness      the reference at the program's own matmul precision
-               (float32, default), in the program's place: no fault, a
-               second witness of what rounding alone moves
-  half_batch   the reference with each step's mean over half its batch
-  double       the reference with its first upload counted twice
-  eval_half    the evaluation of the first half of the test rows only
-  eval_stale   the initial model evaluated in place of the final one
+  program      the program against the family's reference
+  control      the reference one precision below the configuration's
+               (the family's ``CONTROL``), in the program's place
+  witness      the reference at the program's own precision (the
+               family's ``WITNESS``), in the program's place: no fault,
+               a second witness of what rounding alone moves
+  <fault>      each of the family's planted ``FAULTS``: in the
+               reference's rounds, put in the program's place, or in
+               its evaluation
 
-and, under ``dropped``, the leaves (``w, b`` of each layer in turn) that
-the rule on the reference's norms leaves out of ``update1_gap`` and
+and, under ``dropped``, the leaves (indices in wire order) that the rule
+on the reference's norms leaves out of ``update1_gap`` and
 ``change3_gap``.  One JSON line per seed goes to stdout (and to
 ``--out``).  The lower reading of a number is the largest the program
 gives over the seeds; its upper reading the smallest the control or a
@@ -48,8 +48,9 @@ def dropped(leaves) -> list:
 
 def calibrate(cell_name: str, seeds, out=None, require_chip=True,
               cell=None):
-    from bench import check, harness, reference, spec
+    from bench import check, harness, spec
     cell = cell or spec.cell(cell_name)
+    fam = cell.family
     if require_chip:
         harness.device_check(cell.chips)
     rows = []
@@ -64,23 +65,25 @@ def calibrate(cell_name: str, seeds, out=None, require_chip=True,
         t_job = time.perf_counter() - t0
         rounds = int(cell.limits["rounds"])
         t1 = time.perf_counter()
-        ref = harness.reference_side(cell, job, seed, res.final_params)
-        init = reference.init_params(job.features, seed)
-        prog = check.program_side(res, job.capture.payloads, init, rounds)
+        final = fam.final_leaves(res)
+        ref = harness.reference_side(cell, job, final)
+        prog = harness.program_side(cell, job, res, final)
         row = {"seed": seed, "cell": cell.name,
-               "program": check.numbers(prog, ref, rounds)}
+               "program": check.numbers(prog, ref, rounds, fam.QUALITY)}
         t_check = time.perf_counter() - t1
-        planted = {"control": {"dtype": "bfloat16", "precision": "default"},
-                   "witness": {"precision": "default"},
-                   "half_batch": {"fault": "half_batch"},
-                   "double": {"fault": "double"}}
+        planted = {"control": fam.CONTROL, "witness": fam.WITNESS,
+                   **{name: {"fault": name}
+                      for name, part in fam.FAULTS.items()
+                      if part == "rounds"}}
         for name, kw in planted.items():
             row[name] = check.numbers(harness.reference_side(
-                cell, job, seed, res.final_params, **kw), ref, rounds)
-        for name in ("eval_half", "eval_stale"):
-            row[name] = check.numbers(dataclasses.replace(
-                ref, auc=harness.evaluation(job, seed, res.final_params,
-                                            fault=name)), ref, rounds)
+                cell, job, final, **kw), ref, rounds, fam.QUALITY)
+        for name, part in fam.FAULTS.items():
+            if part == "quality":
+                row[name] = check.numbers(dataclasses.replace(
+                    ref, quality=fam.quality(cell, job.data, seed, final,
+                                             fault=name)),
+                    ref, rounds, fam.QUALITY)
         row["dropped"] = {
             "update1_gap": dropped(check._sum(ref.uploads[0])),
             "change3_gap": dropped(check._sum(

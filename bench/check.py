@@ -2,12 +2,14 @@
 
 The program's side is what the timed job produced: every payload it
 encoded (read where the program encodes it, ``comm.wire.encode``, the
-documented boundary every upload passes) read back by the reference
-decoder, its per-round records (participant counts, upload bytes, the
-AUCs of its evaluations) and its final server parameters.  The
-reference side is ``reference.job_rounds`` and ``reference.evaluate``
-(or, for the control and the planted faults, those computed otherwise
-and put in the program's place).
+documented boundary every upload passes) read back by the plain wire
+decoder, its per-round records (participant counts, upload bytes, its
+own evaluations of the model's quality) and its final server
+parameters.  The reference side is the cell's family's
+``reference_rounds`` and ``quality`` (or, for the control and the
+planted faults, those computed otherwise and put in the program's
+place).  Parameters and uploads are lists of float64 leaves in wire
+order, of any number and rank.
 
 Training numbers follow the first ``R`` rounds (``limits["rounds"]``)
 and are gaps of norms taken by the worst leaf: for each parameter leaf
@@ -20,13 +22,15 @@ of the median leaf's (nought to rounding).
   change3_gap     the parameters' change over rounds 1..R
   bytes3_gap      upload bytes over rounds 1..R, relative gap
 
-Evaluation numbers: the largest gap of AUC-ROC or AUC-PR between the
-program's records and a plain evaluation on the whole test split.
+Quality numbers, named by the family's ``QUALITY``: the largest gap of
+any number of the family's quality tuple between the program's records
+and the reference's evaluation.
 
-  auc_init_gap    the first record (the initial model, which the
-                  reference rebuilds from the seed)
-  auc_final_gap   the last record (the final server parameters, which
-                  ``aggregate_gap`` ties to the checked uploads)
+  <QUALITY>_init_gap    the first record (the initial model, which the
+                        reference rebuilds from the seed)
+  <QUALITY>_final_gap   the last record (the final server parameters,
+                        which ``aggregate_gap`` ties to the checked
+                        uploads)
 
 Checks of the program against the reference applied to its own uploads:
 
@@ -47,20 +51,15 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import reference
+from bench import wire
 
 RULE = 1e-3          # leaves under this share of the median leaf norm
-
-
-def _flat(upload) -> List[np.ndarray]:
-    return [a for pair in upload for a in pair]
 
 
 def _sum(uploads) -> Optional[List[np.ndarray]]:
     """The leafwise sum of the uploads; None for no upload."""
     out = None
-    for up in uploads:
-        leaves = _flat(up)
+    for leaves in uploads:
         out = leaves if out is None else [a + b for a, b in zip(out, leaves)]
     return out
 
@@ -87,26 +86,28 @@ def gap_of_difference(prog: List[np.ndarray], ref: List[np.ndarray]
 
 @dataclass
 class Side:
-    """One side of the comparison: per round its uploads (``[(w, b),
-    ...]`` float64 per client) and its upload bytes, and ``auc``, the
-    (AUC-ROC, AUC-PR) of the initial and of the final model.  The
-    program's side also holds its wire faults, its parameters' change
-    over the job and the sum of all its uploads."""
+    """One side of the comparison: per round its uploads (leaves per
+    client) and its upload bytes, and ``quality``, the family's quality
+    tuple of the initial and of the final model.  The program's side
+    also holds its wire faults, its parameters' change over the job and
+    the sum of all its uploads."""
 
     uploads: List[list]
     bytes: List[int]
-    auc: Dict[str, tuple] = field(default_factory=dict)
+    quality: Dict[str, tuple] = field(default_factory=dict)
     wire_faults: int = 0
     final_change: Optional[List[np.ndarray]] = None
     final_sum: Optional[List[np.ndarray]] = None
 
 
-def program_side(result, payloads, init, rounds: int) -> Side:
+def program_side(result, payloads, init, final, quality,
+                 rounds: int) -> Side:
     """The timed job's outputs.  ``payloads`` holds every payload the job
     encoded, in order: round by round, each round's participants in
     turn, as many as its record counts; ``init`` is the reference's
-    initial parameters (the same seed).  Uploads are kept one by one for
-    the first ``rounds`` rounds, and summed for all."""
+    initial parameters (the same seed), ``final`` the job's final ones,
+    ``quality`` the job's recorded quality.  Uploads are kept one by one
+    for the first ``rounds`` rounds, and summed for all."""
     recorded = [int(r.sparse_bytes) for r in result.records]
     counts = [int(r.num_participants) for r in result.records]
     faults = int(sum(counts) != len(payloads))
@@ -114,34 +115,28 @@ def program_side(result, payloads, init, rounds: int) -> Side:
     for r, (rec, n) in enumerate(zip(recorded, counts)):
         ups, sent = [], 0
         for p in payloads[at:at + n]:
-            up, bad = reference.decode(p)
-            sent += reference.upload_bytes(up)
+            leaves, bad = wire.decode(p)
+            sent += wire.upload_bytes(leaves)
             faults += bad
-            leaves = _flat(up)
             every = leaves if every is None else [
                 a + b for a, b in zip(every, leaves)]
             if r < rounds:
-                ups.append(up)
+                ups.append(leaves)
         at += n
         faults += int(sent != rec)
         uploads.append(ups)
-    start = [np.asarray(a, np.float64) for w, b in init for a in (w, b)]
-    final = [np.asarray(a, np.float64)
-             for layer in result.final_params
-             for a in (layer["w"], layer["b"])]
-    first, last = result.records[0], result.records[-1]
-    auc = {"init": (first.auc_roc, first.auc_pr),
-           "final": (last.auc_roc, last.auc_pr)}
-    return Side(uploads, recorded, auc, faults,
-                [f - s for f, s in zip(final, start)], every)
+    return Side(uploads, recorded, quality, faults,
+                [f - s for f, s in zip(final, init)], every)
 
 
-def numbers(prog: Side, ref: Side, rounds: int) -> Dict[str, float]:
-    """Every compared number of ``prog`` against ``ref``."""
+def numbers(prog: Side, ref: Side, rounds: int,
+            quality: str) -> Dict[str, float]:
+    """Every compared number of ``prog`` against ``ref``; ``quality``
+    names the quality numbers (the family's ``QUALITY``)."""
     out = {
         "update1_gap": gap_of_norms(_sum(prog.uploads[0]),
                                     _sum(ref.uploads[0])),
-        "client1_gap": max((gap_of_norms(_flat(p), _flat(r)) for p, r
+        "client1_gap": max((gap_of_norms(p, r) for p, r
                             in zip(prog.uploads[0], ref.uploads[0])),
                            default=float("inf")),
         "change3_gap": gap_of_norms(
@@ -151,8 +146,9 @@ def numbers(prog: Side, ref: Side, rounds: int) -> Dict[str, float]:
         / max(sum(ref.bytes[:rounds]), 1),
     }
     for when in ("init", "final"):
-        out[f"auc_{when}_gap"] = max(abs(float(p) - float(r)) for p, r
-                                     in zip(prog.auc[when], ref.auc[when]))
+        out[f"{quality}_{when}_gap"] = max(
+            abs(float(p) - float(r))
+            for p, r in zip(prog.quality[when], ref.quality[when]))
     if prog.final_change is not None:
         out["aggregate_gap"] = gap_of_difference(prog.final_change,
                                                  prog.final_sum)

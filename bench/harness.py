@@ -1,21 +1,21 @@
 """One run of one cell: set-up, the measured window, and the check.
 
-The window drives ``repro.core.scbf.run_federated`` on its fused path,
-unchanged: each call is one whole federated training job from a fresh
-initialisation (partition, engine build, initial evaluation, rounds in
-fused chunks, evaluation at each chunk boundary).  Every job of a run
-uses the run's seed for data and weights, so the window repeats one
-trajectory and meets no new shapes.
+The window drives the program's entry point, as the cell's family
+(``spec.family``) calls it, unchanged: each call is one whole federated
+training job from a fresh initialisation (partition, engine build,
+initial evaluation, rounds in fused chunks, evaluation at each chunk
+boundary).  Every job of a run uses the run's seed for data and
+weights, so the window repeats one trajectory and meets no new shapes.
 
-Set-up is the process start, the cohort generated on the host from the
+Set-up is the process start, the family's host data made from the
 seed, and one warm-up job identical to a window job, which compiles (or
 loads from the persistent cache) every program the window runs.
 
 With ``trace=1`` the warm-up and one traced job run under the program's
-flight recorder (``repro.obs.trace.recording``, which also turns on its
-device metrics, so the traced job runs that variant of the fused
-program) and the traced job under ``jax.profiler``; the per-layer
-metrics are read from that job.
+flight recorder (``repro.obs.trace.recording``, which turns on its host
+event log alone, so the traced job runs the program the window times)
+and the traced job under ``jax.profiler``; the per-layer metrics are
+read from that job.
 
 The check compares what the last job produced with the plain reference
 (``check.py``); it runs after the window has closed and the memory peak
@@ -31,7 +31,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import check, cohort, reference, spec, tracereduce
+from bench import check, spec, tracereduce
 
 TRACE_DIR = spec.BENCH / "_out" / "trace"
 
@@ -102,99 +102,52 @@ def device_check(chips: int):
     return devices
 
 
-def train_config(cell: spec.Cell, seed: int):
-    from repro.config import FedConfig, ScbfConfig, TrainConfig
-    c, t = cell.config, cell.traffic
-    prune = t.get("prune")
-    scbf = ScbfConfig(
-        upload_rate=c["upload_rate"], selection=c["selection"],
-        num_clients=c["clients"], prune=prune is not None,
-        prune_impl="mask" if prune else "reshape",
-        prune_rate=prune["rate"] if prune else 0.1,
-        prune_total=prune["total"] if prune else 0.47,
-        prune_compact=prune["compact"] if prune else True)
-    fed = FedConfig(fuse_rounds=t["fuse_rounds"], pods=cell.chips,
-                    sample_fraction=c["sample_fraction"], partition="iid")
-    return TrainConfig(learning_rate=c["learning_rate"],
-                       global_loops=t["loops_per_job"],
-                       eval_every=t["eval_every"],
-                       local_epochs=c["local_epochs"],
-                       local_batch_size=c["local_batch_size"],
-                       seed=seed, scbf=scbf, fed=fed)
-
-
-def make_cohort(cell: spec.Cell, seed: int):
-    from repro.data.medical import MedicalCohort
-    return MedicalCohort(*cohort.generate(**cell.config["cohort"],
-                                          seed=seed))
-
-
 class Job:
-    """One federated training job of a cell, as the window runs it."""
+    """One federated training job of a cell, as the window runs it, on
+    the family's data of the seed."""
 
-    def __init__(self, cell: spec.Cell, seed: int, med=None):
+    def __init__(self, cell: spec.Cell, seed: int):
         self.cell = cell
-        self.cohort = med if med is not None else make_cohort(cell, seed)
-        self.cfg = train_config(cell, seed)
-        self.features = tuple(cell.config["features"])
+        self.seed = seed
+        self.data = cell.family.data(cell.config, seed)
         self.capture = Capture()
 
     def __call__(self):
         import jax
-        from repro.core.scbf import run_federated
         self.capture.reset()
-        res = run_federated(self.cohort, self.cfg,
-                            method=self.cell.traffic["method"],
-                            mlp_features=self.features)
+        res = self.cell.family.job(self.cell, self.seed, self.data)
         jax.block_until_ready(res.final_params)
         return res
 
 
-def reference_side(cell: spec.Cell, job: Job, seed: int, final_params,
-                   **kw) -> check.Side:
-    """The first rounds of the job as ``reference.job_rounds`` runs them,
-    and the evaluation of the seed's initial model and of the program's
-    ``final_params`` on the whole test split (``kw``: the arithmetic,
-    ``dtype`` and ``precision``, or a planted ``fault``)."""
-    c = cell.config
-    out = reference.job_rounds(
-        job.cohort.x_train, job.cohort.y_train, features=job.features,
-        num_clients=c["clients"], fraction=c["sample_fraction"],
-        lr=c["learning_rate"], batch=c["local_batch_size"],
-        epochs=c["local_epochs"], upload_rate=c["upload_rate"],
-        selection=c["selection"], seed=seed,
-        rounds=int(cell.limits["rounds"]), prune=cell.traffic.get("prune"),
-        x_val=job.cohort.x_val, **kw)
-    auc = evaluation(job, seed, final_params,
-                     **{k: v for k, v in kw.items() if k != "fault"})
-    return check.Side(out["uploads"], out["bytes"], auc)
+def reference_side(cell: spec.Cell, job: Job, final, **kw) -> check.Side:
+    """The first rounds of the job as the family's reference runs them,
+    and its quality of the seed's initial model and of the program's
+    ``final`` leaves (``kw``: the arithmetic, as the family's ``CONTROL``
+    and ``WITNESS`` give it, or a planted ``fault`` of its ``FAULTS``)."""
+    fam = cell.family
+    out = fam.reference_rounds(cell, job.data, job.seed,
+                               int(cell.limits["rounds"]), **kw)
+    quality = fam.quality(cell, job.data, job.seed, final,
+                          **{k: v for k, v in kw.items() if k != "fault"})
+    return check.Side(out["uploads"], out["bytes"], quality)
 
 
-def evaluation(job: Job, seed: int, final_params, fault=None,
-               **kw) -> dict:
-    """(AUC-ROC, AUC-PR) of the seed's initial model and of
-    ``final_params`` by ``reference.evaluate`` (``kw``: its arithmetic).
-    ``fault`` plants an evaluation fault: ``"eval_half"`` scores the
-    first half of the test rows only, ``"eval_stale"`` scores the
-    initial model in place of the final one."""
-    init = reference.init_params(job.features, seed)
-    final = [(layer["w"], layer["b"]) for layer in final_params]
-    x, y = job.cohort.x_test, job.cohort.y_test
-    if fault == "eval_half":
-        x, y = x[:x.shape[0] // 2], y[:y.shape[0] // 2]
-    if fault == "eval_stale":
-        final = init
-    return {"init": reference.evaluate(init, x, y, **kw),
-            "final": reference.evaluate(final, x, y, **kw)}
+def program_side(cell: spec.Cell, job: Job, res, final) -> check.Side:
+    """What the job's last run produced, against the seed's initial
+    parameters."""
+    fam = cell.family
+    return check.program_side(
+        res, job.capture.payloads, fam.init_leaves(cell.config, job.seed),
+        final, fam.recorded_quality(res), int(cell.limits["rounds"]))
 
 
-def readings(cell: spec.Cell, seed: int, job: Job, res) -> Dict[str, float]:
+def readings(cell: spec.Cell, job: Job, res) -> Dict[str, float]:
     """The compared numbers of the job's last run against the reference."""
-    init = reference.init_params(job.features, seed)
-    rounds = int(cell.limits["rounds"])
-    prog = check.program_side(res, job.capture.payloads, init, rounds)
-    return check.numbers(prog, reference_side(cell, job, seed,
-                                              res.final_params), rounds)
+    final = cell.family.final_leaves(res)
+    return check.numbers(program_side(cell, job, res, final),
+                         reference_side(cell, job, final),
+                         int(cell.limits["rounds"]), cell.family.QUALITY)
 
 
 def _memory_peak(devices) -> int:
@@ -268,7 +221,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     counter = CompileCounter()
     t_devices = time.perf_counter()
     job = Job(cell, seed)
-    t_cohort = time.perf_counter()
+    t_data = time.perf_counter()
     job.capture.install()
     try:
         if trace:
@@ -300,8 +253,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     finally:
         job.capture.uninstall()
     print(f"setup: {t_devices - t_start:.3f} s to the devices, "
-          f"{t_cohort - t_devices:.3f} s cohort, "
-          f"{t_start + setup_s - t_cohort:.3f} s warm-up job", file=log)
+          f"{t_data - t_devices:.3f} s data, "
+          f"{t_start + setup_s - t_data:.3f} s warm-up job", file=log)
     print(f"window: {jobs} jobs, {rounds} rounds, "
           f"{counter.count} compilations inside", file=log)
     if not trace:
@@ -338,7 +291,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
             value = spec.reader(m["name"]).read(ctx)
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
-    values = readings(cell, seed, job, res)
+    values = readings(cell, job, res)
     limits = {k: float(v) for k, v in cell.limits["limits"].items()}
     # numbers without a limit (no readings to set one from yet) are
     # printed beside the compared ones, and decide nothing

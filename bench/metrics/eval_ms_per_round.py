@@ -1,5 +1,6 @@
 """eval_ms_per_round: the program's ``eval`` span (core/scbf
-``_evaluate``: the test-set forward pass and AUC) per round."""
+``_evaluate``: the test-set forward pass and its quality numbers) per
+round."""
 LAYER = "evaluation"
 UNIT = "ms/round"
 BETTER = "lower"

@@ -1,7 +1,7 @@
 """job_setup_ms_per_round: the program's ``job_setup`` span (core/scbf
 ``run_federated``: model initialisation, partition, engine build with
-the cohort's copy to the device, scheduler, strategy and lr table) per
-round."""
+the training data's copy to the device, scheduler, strategy and lr
+table) per round."""
 LAYER = "planning, host→device"
 UNIT = "ms/round"
 BETTER = "lower"

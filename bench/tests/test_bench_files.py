@@ -1,7 +1,8 @@
 """BENCHMARK.json and the files it names keep to the benchmark's
 contract: every file loads, names and units use the allowed characters,
-each metric's reader agrees with its entry, and every per-layer metric
-is reported by cells that report the metric it moves."""
+each metric's reader agrees with its entry, every per-layer metric is
+reported by cells that report the metric it moves, and the generic
+files name no model: what is a model's is its family's."""
 import re
 
 import pytest
@@ -51,7 +52,9 @@ def test_names_and_units():
 def test_cell_files_load(cell):
     c = spec.cell(cell, BM)
     assert c.chips in (1, 4)
-    assert c.config["features"][0] == c.config["cohort"]["medicines"]
+    c.family.check_config(c.config)
+    assert c.family.__file__ == str(
+        spec.BENCH / "families" / f"{c.config['family']}.py")
     assert int(c.limits["rounds"]) >= 1
     assert c.limits["limits"]
     entry = next(x for x in BM["configs"]
@@ -90,3 +93,20 @@ def test_check_fits_in_the_budget():
     runs = 2 + 14 * 24
     need = runs * (BM["run_seconds"] + 60) + 24 * 2 * 90 + 1200
     assert need <= 43200
+
+
+# the files every cell runs through, whatever its model
+GENERIC = ["harness.py", "check.py", "calibrate.py", "spec.py", "run.py",
+           "tracereduce.py", "wire.py"] + sorted(
+    f"metrics/{p.name}" for p in (spec.BENCH / "metrics").glob("*.py"))
+# what belongs to the MLP family: its data, its widths, its parameter
+# structure, its quality numbers
+MLP_WORDS = re.compile(r"features|cohort|medic|mlp|\[[\"']w[\"']\]"
+                       r"|\[[\"']b[\"']\]|auc", re.IGNORECASE)
+
+
+@pytest.mark.parametrize("path", GENERIC)
+def test_generic_file_names_no_model(path):
+    text = (spec.BENCH / path).read_text()
+    assert not [(i, line) for i, line in enumerate(text.splitlines(), 1)
+                if MLP_WORDS.search(line)]

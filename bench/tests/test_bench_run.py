@@ -84,10 +84,11 @@ def test_control_is_not_correct(name):
     cell = tiny.cell(name)
     job = harness.Job(cell, SEED)
     res = job()
-    ref = harness.reference_side(cell, job, SEED, res.final_params)
+    final = cell.family.final_leaves(res)
+    ref = harness.reference_side(cell, job, final)
     ctl = check.numbers(harness.reference_side(
-        cell, job, SEED, res.final_params, dtype="bfloat16",
-        precision="default"), ref, int(cell.limits["rounds"]))
+        cell, job, final, **cell.family.CONTROL), ref,
+        int(cell.limits["rounds"]), cell.family.QUALITY)
     limits = cell.limits["limits"]
     assert any(v > limits.get(k, float("inf"))
                for k, v in ctl.items()), ctl
@@ -187,21 +188,21 @@ def test_masked_pruning_agrees_with_reference():
     res = job()
     c = cell.config
     ref = reference.job_rounds(
-        job.cohort.x_train, job.cohort.y_train, features=job.features,
+        job.data.x_train, job.data.y_train, features=tuple(c["features"]),
         num_clients=c["clients"], fraction=c["sample_fraction"],
         lr=c["learning_rate"], batch=c["local_batch_size"],
         epochs=c["local_epochs"], upload_rate=c["upload_rate"],
         selection=c["selection"], seed=SEED, rounds=len(res.records),
-        prune=cell.traffic["prune"], x_val=job.cohort.x_val)
+        prune=cell.traffic["prune"], x_val=job.data.x_val)
     widths = [tuple(int(k.sum()) for k in keep) for keep in ref["keeps"]]
     assert [tuple(r.hidden_sizes) for r in res.records] == widths
     assert widths[0] != widths[-1] != tuple(c["features"][1:-1])
     assert [r.sparse_bytes for r in res.records] == ref["bytes"]
-    final = [np.asarray(a, np.float64) for layer in res.final_params
-             for a in (layer["w"], layer["b"])]
-    want = reference.effective(ref["params"], ref["keeps"][-1])
-    assert [a.shape for a in final] == [a.shape for a in check._flat(want)]
-    assert check.gap_of_difference(final, check._flat(want)) < 1e-4
+    final = cell.family.final_leaves(res)
+    want = cell.family.leaves(reference.effective(ref["params"],
+                                                  ref["keeps"][-1]))
+    assert [a.shape for a in final] == [a.shape for a in want]
+    assert check.gap_of_difference(final, want) < 1e-4
 
 
 @pytest.mark.parametrize("scores,labels,roc,ap", [

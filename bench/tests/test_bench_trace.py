@@ -93,11 +93,11 @@ def test_recorded_trace(chip_trace):
 
 
 def test_example_flops_by_hand():
-    mfu = spec.reader("train_mfu")
+    mlp = spec.family("mlp")
     # forward 2*(2917*256 + 256*64 + 64*1), the same again for the
     # weight gradients, and the activation gradients of layers 2 and 3
     fwd = 2 * (2917 * 256 + 256 * 64 + 64)
-    assert mfu.example_flops((2917, 256, 64, 1)) \
+    assert mlp.example_flops((2917, 256, 64, 1)) \
         == 2 * fwd + 2 * (256 * 64 + 64) == 3_085_696
 
 
@@ -116,14 +116,30 @@ class _Rec:
       "local_epochs": 5}, 18 * 10 * 5),
 ])
 def test_job_flops_by_hand(over, examples):
-    mfu = spec.reader("train_mfu")
-    c = dict(spec.cell("silo5-scbf").config, **over)
+    cell = spec.cell("silo5-scbf")
+    c = dict(cell.config, **over)
     per = round(c["sample_fraction"] * c["clients"])
     recs = [_Rec(per), _Rec(per)]
-    assert mfu.job_flops(c, recs) == 2 * per * examples * 3_085_696
+    assert cell.family.job_flops(c, recs) == 2 * per * examples * 3_085_696
     pruned = [_Rec(per, (128, 32))]
     small = 2 * (2 * (2917 * 128 + 128 * 32 + 32)) + 2 * (128 * 32 + 32)
-    assert mfu.job_flops(c, pruned) == per * examples * small
+    assert cell.family.job_flops(c, pruned) == per * examples * small
+
+
+# the train_mfu reader's own count, before it moved into the family
+@pytest.mark.parametrize("records,flops", [
+    ([_Rec(5), _Rec(5)], 221_182_689_280),
+    ([_Rec(5, (128, 32))], 54_414_868_480),
+    ([_Rec(5)] * 30, 3_317_740_339_200),
+])
+def test_train_mfu_reads_the_family_count(records, flops):
+    cell = spec.cell("silo5-scbf")
+    assert cell.family.job_flops(cell.config, records) == flops
+    ctx = _ctx(cell=cell, records=records, chips=1, window_s=2.0,
+               peak={"bf16_flops_per_s": 197e12})
+    assert spec.reader("train_mfu").read(ctx) == pytest.approx(
+        100.0 * flops / (2.0 * 197e12))
+    assert spec.reader("train_mfu").read(_ctx(cell=cell, planes=[])) is None
 
 
 def _ctx(**kw):
