@@ -85,6 +85,10 @@ class LoopRecord:
     # fair amortized figure, NOT a per-round measurement (the S rounds
     # ran as one device program, so no per-round wall exists)
     wall_is_amortized: bool = False
+    # client slots the round's program trained: the fused chunk's
+    # run-constant B, or the per-round engine's bucket (0 when nothing
+    # was dispatched); ``slots - num_participants`` trained padding
+    slots: int = 0
 
 
 @dataclass
@@ -680,7 +684,7 @@ def run_federated(cohort: MedicalCohort,
             wall_time=wall,
             flops_proxy=float(n_params) * cohort.x_train.shape[0],
             hidden_sizes=hidden,
-            num_participants=P,
+            num_participants=P, slots=eng.round_slots(P),
             epsilon=eps, evaluated=evaluated, epsilon_unamplified=eps_un,
             train_loss=dm.get("train_loss") if dm else None)
         result.records.append(rec)
@@ -713,6 +717,7 @@ def _round_event_fields(rec: LoopRecord, plan, pruner, dm,
     """
     out = {
         "loop": rec.loop, "participants": rec.num_participants,
+        "slots": rec.slots,
         "upload_fraction": round(rec.upload_fraction, 6),
         "sparse_bytes": rec.sparse_bytes, "dense_bytes": rec.dense_bytes,
         "wall": round(rec.wall_time, 6),
@@ -978,7 +983,7 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
                     sparse_bytes=sparse_bytes, dense_bytes=dense_bytes,
                     wall_time=wall_each,
                     flops_proxy=float(n_params) * cohort.x_train.shape[0],
-                    hidden_sizes=hidden, num_participants=P,
+                    hidden_sizes=hidden, num_participants=P, slots=B,
                     epsilon=eps, evaluated=evaluated,
                     epsilon_unamplified=eps_un,
                     train_loss=(dm or {}).get("train_loss")

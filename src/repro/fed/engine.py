@@ -604,6 +604,13 @@ class BatchedEngine:
             return self.cohort.x, self.cohort.y, self.cohort.w
         return self.cohort.x[part], self.cohort.y[part], self.cohort.w[part]
 
+    def round_slots(self, num_participants: int) -> int:
+        """Slots a per-round program trains for ``num_participants``
+        reporters: their bucket (0 for an empty round, which dispatches
+        nothing)."""
+        return bucket_size(num_participants, self.num_clients, self.bucket,
+                           self.pods)
+
     def _bucketed_inputs(self, participants, slot_arrays, key_arrays=(),
                          params=None):
         """Pad per-slot arrays up to the bucket; returns (B, arrays,
@@ -614,7 +621,7 @@ class BatchedEngine:
         sharded over ``pod`` and params replicated.
         """
         p_count = len(participants)
-        b = bucket_size(p_count, self.num_clients, self.bucket, self.pods)
+        b = self.round_slots(p_count)
         valid = jnp.arange(b) < p_count
         out = [_pad_slots(jnp.asarray(a), b) for a in slot_arrays]
         keys = [_pad_key_slots(k, b) for k in key_arrays]
@@ -945,6 +952,10 @@ class SequentialEngine:
     @property
     def num_clients(self) -> int:
         return len(self.clients)
+
+    def round_slots(self, num_participants: int) -> int:
+        """One program per participant: no padded slot."""
+        return num_participants
 
     def scbf_round(self, params, participants, lr, ckeys, skeys, dp_keys,
                    cfg: ScbfConfig, nmasks=None, keep=None,

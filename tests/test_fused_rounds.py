@@ -14,6 +14,7 @@ from _trace_guards import assert_compiles, assert_no_transfers
 from repro.config import FedConfig, ScbfConfig, TrainConfig
 from repro.core.scbf import run_federated
 from repro.data.medical import generate_cohort
+from repro.fed.cohort import bucket_size
 from repro.fed.engine import make_engine
 from repro.fed.scheduler import make_scheduler
 from repro.models.mlp_net import init_mlp
@@ -111,6 +112,46 @@ def test_fused_matches_per_round_varying_bucketed_p(cohort):
     # guard against a vacuous pass: real training, real uploads
     assert sum(r.sparse_bytes for r in a.records) > 0
     _assert_trajectories_match(a, b)
+
+
+@pytest.mark.parametrize("K,fraction,slots", [(5, 1.0, 5), (12, 0.5, 8)],
+                         ids=["5of5", "6of12"])
+def test_records_count_the_slots_trained(cohort, K, fraction, slots):
+    """``LoopRecord.slots`` is the bucket the round's program trained, on
+    the fused and the per-round path alike; it is an observation only,
+    and the two paths still agree bit for bit on everything else."""
+    kw = dict(loops=4, K=K, batch=16, sample_fraction=fraction)
+    a = run_federated(cohort, _tcfg(1, **kw), method="scbf",
+                      mlp_features=FEATS)
+    b = run_federated(cohort, _tcfg(2, **kw), method="scbf",
+                      mlp_features=FEATS)
+    P = round(fraction * K)
+    assert bucket_size(P, K, "pow2", 1) == slots
+    for res in (a, b):
+        assert [(r.num_participants, r.slots) for r in res.records] \
+            == [(P, slots)] * 4
+    assert sum(r.sparse_bytes for r in a.records) > 0
+    _assert_trajectories_match(a, b)
+    seq = run_federated(cohort, _tcfg(1, **kw), method="scbf",
+                        mlp_features=FEATS, engine="sequential")
+    assert [r.slots for r in seq.records] == [P] * 4
+
+
+def test_fused_slots_are_the_largest_cohorts_bucket(cohort):
+    """Under dropout P varies: the fused chunk trains the run-constant
+    bucket of the scheduler's largest cohort every round, the per-round
+    engine each round's own bucket (none for an empty round)."""
+    kw = dict(loops=7, K=8, batch=32, sample_fraction=0.5,
+              dropout_rate=0.25)
+    a = run_federated(cohort, _tcfg(1, **kw), method="scbf",
+                      mlp_features=FEATS)
+    b = run_federated(cohort, _tcfg(3, **kw), method="scbf",
+                      mlp_features=FEATS)
+    ps = [r.num_participants for r in a.records]
+    assert len(set(ps)) > 1
+    assert [r.slots for r in a.records] == [bucket_size(p, 8, "pow2", 1)
+                                            for p in ps]
+    assert {r.slots for r in b.records} == {bucket_size(4, 8, "pow2", 1)}
 
 
 def test_fused_fedavg_matches_per_round(cohort):
