@@ -40,7 +40,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -742,6 +742,16 @@ def _round_event_fields(rec: LoopRecord, plan, pruner, dm,
     return out
 
 
+class _Chunk(NamedTuple):
+    """One planned fused chunk: what the driver dispatches and what its
+    records read back."""
+    prune_active: bool
+    ars: list           # the AdmittedRound of each real round
+    fplan: object       # the engine's FusedPlan
+    keep: Optional[List[np.ndarray]]  # emission keep sets (mask mode)
+    version: int        # the server version after the chunk
+
+
 def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
                eng, resil, state, key, lrs: np.ndarray,
                dp_releases: np.ndarray, result: RunResult,
@@ -759,6 +769,16 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
     the returned (S, B) masked deltas, so per-round upload accounting is
     byte-identical to the per-round path.  Evaluation coarsens to chunk
     boundaries (docs/FED_ENGINE.md §Fused round loop).
+
+    The chunks run as a two-stage pipeline: the next chunk is planned
+    while this one runs, and dispatched as soon as this one is done and
+    its model has been read (the boundary evaluation and debug checks:
+    the dispatch donates that model's buffers), so the device trains
+    chunk k+1 while the host pulls, encodes and records chunk k.  Plans
+    and keys are drawn in the same order as without the pipeline.  The
+    driver runs ahead only where chunk k+1's inputs do not depend on
+    chunk k's outputs: never from the last chunk, nor while pruning
+    (the prune step after a chunk sets the next chunk's keep-masks).
 
     SCBFwP (``pruner``, always mask-mode here): geometry stays
     run-constant, the keep-mask tuple rides into each chunk as a plain
@@ -795,6 +815,86 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
                 for l in state.params)
         return n, tuple(pruning.hidden_sizes(state.params))
 
+    def _prune_active() -> bool:
+        return pruner is not None and pruner.active
+
+    def _plan(loop0: int) -> _Chunk:
+        """Plan the chunk that starts at ``loop0`` from the server state
+        after the chunk before it (its version, its model's shapes)."""
+        nonlocal key
+        prune_active = _prune_active()
+        chunk = fused_chunk_len(total_loops - loop0, S, prune_active)
+        with obstrace.span("plan", rounds=chunk):
+            # the resilient planner replaces plan_horizon: same
+            # scheduler.plan sequence underneath (bit-parity when the
+            # fault model is off), plus fault outcomes and quorum
+            # resolved per round at plan time — which is what lets the
+            # admission verdicts fold into the static (S, B) admit mask
+            ars = [resil.plan_round(loop0 + i, state.version)
+                   for i in range(chunk)]
+            parts, cks, sks, dks, wts = [], [], [], [], []
+            for ar in ars:
+                part = ar.plan.participants
+                P = ar.plan.num_participants
+                # _derive_round_keys is the single key-stream contract,
+                # so the fused pre-planner consumes EXACTLY what the
+                # per-round loop would have
+                key, ck, sk, dk = _derive_round_keys(key, cfg.num_clients,
+                                                     part, P)
+                cks.append(np.asarray(ck))
+                sks.append(np.asarray(sk))
+                dks.append(np.asarray(dk))
+                parts.append(part)
+                if method == "fedavg":
+                    if P and ar.quorum_ok:
+                        n = eng.counts[np.asarray(part)].astype(np.float64)
+                        wts.append((n / n.sum()).astype(np.float32))
+                    else:
+                        # quorum-missed rounds must not step: all-zero
+                        # weights pass the fedavg carry through bitwise
+                        wts.append(np.zeros(P, np.float32))
+            keep_eff = pruner.emission_keep if pruner is not None else None
+            eff = obsm.effective_leaf_sizes(state.params, keep_eff) \
+                if (collect and method == "scbf"
+                    and keep_eff is not None) else None
+            admits = [ar.admit_mask() for ar in ars] if resil.active \
+                else None
+            fplan = eng.prepare_fused_plan(
+                parts, lrs[loop0:loop0 + chunk], cks, sks, dks,
+                horizon=1 if prune_active else S, num_slots=B,
+                weights=wts if method == "fedavg" else None,
+                eff_sizes=eff, admit=admits)
+        # a round bumps the version iff it passed quorum AND at least
+        # one slot was admitted — the same rule ScbfSum's admission gate
+        # applies on the per-round path (fault-free, admit == valid,
+        # this is the old "any participants" count)
+        applied = sum(1 for ar in ars
+                      if ar.quorum_ok and bool(ar.admit_mask().any()))
+        return _Chunk(prune_active, ars, fplan, keep_eff,
+                      state.version + applied)
+
+    def _dispatch(c: _Chunk):
+        """Start chunk ``c`` on the device from the current model (whose
+        buffers the call donates on backends that support donation).
+
+        Returns ``(new_params, (masked_s, masks_s), met_s)``: the
+        stacks to emit are None for FedAvg, the on-device metrics None
+        unless ``collect``.  One return, after the branches: privlint's
+        taint analysis joins a function's returns, and a join would let
+        FedAvg's un-noised model stand for SCBF's in the next chunk.
+        """
+        if method == "fedavg":
+            res = eng.fused_fedavg_chunk(state.params, c.fplan,
+                                         collect=collect)
+            out = (res[0], None, res[1]) if collect else (res, None, None)
+        else:
+            res = eng.fused_scbf_chunk(
+                state.params, c.fplan, cfg,
+                nmasks=pruner.masks if pruner is not None else None,
+                collect=collect)
+            out = res[0], (res[1], res[2]), res[3] if collect else None
+        return out
+
     if min(S, total_loops) > 1:
         # the first chunk's non-boundary records will need last-known
         # metrics, so the initial-model evaluation always happens — do
@@ -804,95 +904,47 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
         _metrics(state.params, True,
                  pruner.masks if pruner is not None else None)
 
+    # the key stream is split on the host's CPU backend (threefry gives
+    # the same bits on every backend), so planning chunk k+1 does not
+    # queue behind chunk k's program: a device runs its programs in order
+    key = jax.device_put(key, jax.devices("cpu")[0])
     loop0 = 0
     prev_eps = 0.0
+    cur = out = None       # the chunk dispatched ahead, and its outputs
+    # chunks dispatched before their predecessor's emission (present,
+    # at 0, on every recorded fused run)
+    obstrace.count("fused_dispatch_ahead", 0)
     while loop0 < total_loops:
-        prune_active = pruner is not None and pruner.active
-        chunk = fused_chunk_len(total_loops - loop0, S, prune_active)
-        # the chunk span replaces the hand-rolled perf_counter pair: it
-        # covers plan → keys → chunk dispatch → emit → prune
+        # a chunk planned ahead was planned with this same pruner state
+        chunk = fused_chunk_len(total_loops - loop0, S, _prune_active())
+        nxt = nxt_out = None
+        # the chunk span (its wall / rounds is each record's wall time)
+        # covers (when nothing ran ahead) this chunk's plan and dispatch
+        # → the next chunk's plan → the wait → pruning and the boundary
+        # evaluation → the next chunk's dispatch → this chunk's emission
         with obstrace.span("fused_chunk", loop0=loop0, rounds=chunk) as sp:
-            with obstrace.span("plan", rounds=chunk):
-                # the resilient planner replaces plan_horizon: same
-                # scheduler.plan sequence underneath (bit-parity when the
-                # fault model is off), plus fault outcomes and quorum
-                # resolved per round at plan time — which is what lets the
-                # admission verdicts fold into the static (S, B) admit mask
-                ars = [resil.plan_round(loop0 + i, state.version)
-                       for i in range(chunk)]
-                plans = [ar.plan for ar in ars]
-                parts, cks, sks, dks, wts = [], [], [], [], []
-                for ar, plan in zip(ars, plans):
-                    part = plan.participants
-                    P = plan.num_participants
-                    # _derive_round_keys is the single key-stream contract,
-                    # so the fused pre-planner consumes EXACTLY what the
-                    # per-round loop would have
-                    key, ck, sk, dk = _derive_round_keys(key, cfg.num_clients,
-                                                         part, P)
-                    cks.append(np.asarray(ck))
-                    sks.append(np.asarray(sk))
-                    dks.append(np.asarray(dk))
-                    parts.append(part)
-                    if method == "fedavg":
-                        if P and ar.quorum_ok:
-                            n = eng.counts[np.asarray(part)].astype(np.float64)
-                            wts.append((n / n.sum()).astype(np.float32))
-                        else:
-                            # quorum-missed rounds must not step: all-zero
-                            # weights pass the fedavg carry through bitwise
-                            wts.append(np.zeros(P, np.float32))
-                keep_eff = pruner.emission_keep if pruner is not None else None
-                eff = obsm.effective_leaf_sizes(state.params, keep_eff) \
-                    if (collect and method == "scbf"
-                        and keep_eff is not None) else None
-                admits = [ar.admit_mask() for ar in ars] if resil.active \
-                    else None
-                fplan = eng.prepare_fused_plan(
-                    parts, lrs[loop0:loop0 + chunk], cks, sks, dks,
-                    horizon=1 if prune_active else S, num_slots=B,
-                    weights=wts if method == "fedavg" else None,
-                    eff_sizes=eff, admit=admits)
-            round_metrics = None
-            if method == "scbf":
-                out = eng.fused_scbf_chunk(
-                    state.params, fplan, cfg,
-                    nmasks=pruner.masks if pruner is not None else None,
-                    collect=collect)
-                if collect:
-                    new_params, masked_s, masks_s, met_s = out
-                else:
-                    new_params, masked_s, masks_s = out
-                emitted = eng.emit_fused_payloads(
-                    masked_s, masks_s, fplan, keep=keep_eff)
-                if collect:
-                    # the chunk-boundary offload: ONE device_get for the
-                    # whole chunk's telemetry, alongside the payload pull
-                    round_metrics = obsm.offload(met_s,
-                                                 rounds=fplan.rounds)
-            else:
-                out = eng.fused_fedavg_chunk(state.params, fplan,
-                                             collect=collect)
-                if collect:
-                    new_params, met_s = out
-                    round_metrics = obsm.offload(met_s,
-                                                 rounds=fplan.rounds)
-                else:
-                    new_params = out
-                emitted = [([], [])] * chunk
-            # a round bumps the version iff it passed quorum AND at
-            # least one slot was admitted — the same rule ScbfSum's
-            # admission gate applies on the per-round path (fault-free,
-            # admit == valid, this is the old "any participants" count)
-            applied = sum(1 for ar in ars
-                          if ar.quorum_ok and bool(ar.admit_mask().any()))
+            if cur is None:
+                cur = _plan(loop0)
+                out = _dispatch(cur)
+            new_params, stacks, met_s = out
             state = dataclasses.replace(state, params=new_params,
-                                        version=state.version + applied)
+                                        version=cur.version)
+            ahead = loop0 + chunk < total_loops and not cur.prune_active
+            if ahead:
+                # host planning under this chunk's program
+                nxt = _plan(loop0 + chunk)
+            with obstrace.span("emit_wait"):
+                jax.block_until_ready(out)
+            # everything that reads this chunk's model runs before the
+            # next dispatch donates it: telemetry, checks, pruning, the
+            # boundary evaluation
+            round_metrics = obsm.offload(met_s, rounds=cur.fplan.rounds) \
+                if collect else None
             if train_cfg.debug_checks:
                 # host-side, on the values the chunk already offloaded
                 obschecks.verify_round(state.params, round_metrics,
                                        where=f"chunk@loop {loop0}")
-            if prune_active:
+            if cur.prune_active:
                 # chunk boundary == per-round cadence while pruning
                 # (chunks are 1 round long): APoZ on device, mask update
                 # on host
@@ -904,13 +956,29 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
                         state, params=pruner.compact(state.params))
                     obstrace.event("compact", loop=loop0,
                                    hidden=list(pruner.hidden_sizes()))
+            n_params, hidden = _model_stats()
+            quality = [_metrics(state.params,
+                                r == chunk - 1 and _should_eval(
+                                    loop0 + r, total_loops,
+                                    train_cfg.eval_every),
+                                pruner.masks if pruner is not None
+                                else None)
+                       for r in range(chunk)]
+            if ahead:
+                nxt_out = _dispatch(nxt)
+                obstrace.count("fused_dispatch_ahead")
+            if method == "scbf":
+                masked_s, masks_s = stacks
+                emitted = eng.emit_fused_payloads(masked_s, masks_s,
+                                                  cur.fplan, keep=cur.keep)
+            else:
+                emitted = [([], [])] * chunk
         wall_each = sp.elapsed / chunk
 
-        # the chunk's per-round records; the chunk-boundary ``eval`` nests
-        # inside
+        # the chunk's per-round records, while the next chunk runs
         with obstrace.span("records", loop0=loop0, rounds=chunk):
-            n_params, hidden = _model_stats()
-            for r, (ar, plan) in enumerate(zip(ars, plans)):
+            for r, ar in enumerate(cur.ars):
+                plan = ar.plan
                 loop = loop0 + r
                 P = plan.num_participants
                 payloads, stats = emitted[r]
@@ -928,7 +996,8 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
                         # match the per-round path, and the verdicts are
                         # checked against the plan the device already
                         # folded in (any divergence is a hard error, never
-                        # a silent one)
+                        # a silent one); the gate reads the server's
+                        # nonces, never its (donated) model
                         cl = np.asarray(plan.participants)
                         wire_payloads, dup_src = apply_payload_faults(
                             payloads, cl, ar.corrupt, ar.duplicated, loop,
@@ -971,11 +1040,7 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
                     up_frac = 1.0 if P else 0.0
                     dense_bytes = n_params * 4 * P
                     sparse_bytes = dense_bytes
-                do_eval = (r == chunk - 1) and _should_eval(
-                    loop, total_loops, train_cfg.eval_every)
-                roc, pr, evaluated = _metrics(
-                    state.params, do_eval,
-                    pruner.masks if pruner is not None else None)
+                roc, pr, evaluated = quality[r]
                 eps, eps_un = _epsilons(loop)
                 rec = LoopRecord(
                     loop=loop, auc_roc=roc, auc_pr=pr,
@@ -1001,4 +1066,5 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
                           f"clients={P} t={wall_each:.2f}s"
                           + ("" if evaluated else " (metrics carried)"))
         loop0 += chunk
+        cur, out = nxt, nxt_out
     result.final_params = state.params

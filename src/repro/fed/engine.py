@@ -416,20 +416,18 @@ def _encode_slot(masked_host, masks_host, sl, keep=None):
 
 def _pull_and_encode(masked_stacked, masks_stacked, slots, keep=None
                      ) -> Tuple[List[wire.Payload], List[sel.UploadStats]]:
-    """Wait for the device, pull both stacks to the host in one
-    transfer, then wire-encode every slot in ``slots`` (index tuples
-    into the stacked leading axes, see ``_encode_slot``).
+    """Pull both stacks to the host in one transfer, then wire-encode
+    every slot in ``slots`` (index tuples into the stacked leading axes,
+    see ``_encode_slot``).
 
-    The three stretches are spans of their own — ``emit_wait`` (the host
-    blocked on the device work that produced the stacks), ``emit_pull``
-    (the device→host copy, ``bytes`` pulled) and ``wire_encode`` (the
-    NumPy encoding) — shared by the per-round and the fused emitters.
-    The wait adds no synchronisation: the pull waits for the same
-    results.
+    The two stretches are spans of their own — ``emit_pull`` (the
+    device→host copy, ``bytes`` pulled) and ``wire_encode`` (the NumPy
+    encoding) — shared by the per-round and the fused emitters.  The
+    wait for the device work that produced the stacks (``emit_wait``)
+    is the caller's: the per-round emitter waits just before the pull,
+    the fused driver before it dispatches the next chunk.
     """
     stacks = (masked_stacked, masks_stacked)
-    with obstrace.span("emit_wait"):
-        jax.block_until_ready(stacks)
     pulled = sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(stacks))
     with obstrace.span("emit_pull", bytes=pulled):
         masked_host, masks_host = jax.device_get(stacks)
@@ -451,6 +449,8 @@ def _emit_payloads(masked_stacked, masks_stacked, num: int, keep=None
     encoded — padded slots ship zero bytes.
     """
     with obstrace.span("encode", clients=num):
+        with obstrace.span("emit_wait"):
+            jax.block_until_ready((masked_stacked, masks_stacked))
         return _pull_and_encode(masked_stacked, masks_stacked,
                                 [(i,) for i in range(num)], keep)
 
@@ -903,7 +903,9 @@ class BatchedEngine:
                             ) -> List[Tuple[List[wire.Payload],
                                             List[sel.UploadStats]]]:
         """One device→host transfer for the whole chunk, then per-round
-        wire encoding off the critical path.
+        wire encoding off the critical path: the fused driver calls it
+        once the chunk is done (its ``emit_wait``) and the next chunk
+        is already running on the device.
 
         ``keep`` (mask-mode SCBFwP) compacts every slot to the
         effective geometry before encoding, so the reported bytes are

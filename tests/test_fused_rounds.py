@@ -11,13 +11,14 @@ import numpy as np
 import pytest
 
 from _trace_guards import assert_compiles, assert_no_transfers
-from repro.config import FedConfig, ScbfConfig, TrainConfig
+from repro.config import FaultConfig, FedConfig, ScbfConfig, TrainConfig
 from repro.core.scbf import run_federated
 from repro.data.medical import generate_cohort
 from repro.fed.cohort import bucket_size
-from repro.fed.engine import make_engine
+from repro.fed.engine import BatchedEngine, make_engine
 from repro.fed.scheduler import make_scheduler
 from repro.models.mlp_net import init_mlp
+from repro.obs import trace as obstrace
 
 
 @pytest.fixture(scope="module")
@@ -65,6 +66,13 @@ def _assert_trajectories_match(per_round, fused, bitwise_params=True):
                                      fused.final_params)
 
 
+def _recorded(cohort, cfg, method="scbf"):
+    rec = obstrace.Recorder()
+    with obstrace.recording(recorder=rec):
+        res = run_federated(cohort, cfg, method=method, mlp_features=FEATS)
+    return res, rec
+
+
 # ---------------------------------------------------------------------------
 # parity: the tentpole acceptance criterion
 # ---------------------------------------------------------------------------
@@ -96,22 +104,28 @@ def test_fused_matches_per_round_with_dp(cohort):
     assert all(r.epsilon is not None for r in b.records)
 
 
-def test_fused_matches_per_round_varying_bucketed_p(cohort):
+@pytest.mark.parametrize("loops,fuse", [(7, 3), (25, 10)],
+                         ids=["7x3", "25x10"])
+def test_fused_matches_per_round_varying_bucketed_p(cohort, loops, fuse):
     """Sampling + dropout make P vary across bucket boundaries; the
     fused plan pads every round to one run-constant slot count, and the
     real slots must stay bit-identical to the per-round bucketed
-    engine."""
-    kw = dict(loops=7, K=8, batch=32, sample_fraction=0.5,
+    engine.  Every chunk but the first is dispatched before its
+    predecessor's emission (25 rounds at S=10: three chunks, the last
+    one padded)."""
+    kw = dict(loops=loops, K=8, batch=32, sample_fraction=0.5,
               dropout_rate=0.25)
     a = run_federated(cohort, _tcfg(1, **kw), method="scbf",
                       mlp_features=FEATS)
-    b = run_federated(cohort, _tcfg(3, **kw), method="scbf",
-                      mlp_features=FEATS)
+    b, _ = _recorded(cohort, _tcfg(fuse, **kw))
     ps = [r.num_participants for r in a.records]
     assert len({p for p in ps if p}) > 1      # P actually varies
     # guard against a vacuous pass: real training, real uploads
     assert sum(r.sparse_bytes for r in a.records) > 0
     _assert_trajectories_match(a, b)
+    chunks = -(-loops // fuse)
+    assert b.telemetry["fused_dispatch_ahead"] == chunks - 1
+    assert [r.evaluated for r in b.records].count(True) == chunks
 
 
 @pytest.mark.parametrize("K,fraction,slots", [(5, 1.0, 5), (12, 0.5, 8)],
@@ -158,11 +172,13 @@ def test_fused_fedavg_matches_per_round(cohort):
     """Fused FedAvg aggregates on device too.  XLA contracts the
     weight-multiply-accumulate inside the fused program (FMA), so
     parity here is allclose-tight rather than bitwise — the scbf path
-    (pure adds, nothing to contract) is the bitwise one."""
+    (pure adds, nothing to contract) is the bitwise one.  Its chunks
+    (no emission) go through the same pipeline: the second is
+    dispatched ahead."""
     a = run_federated(cohort, _tcfg(1, loops=5), method="fedavg",
                       mlp_features=FEATS)
-    b = run_federated(cohort, _tcfg(3, loops=5), method="fedavg",
-                      mlp_features=FEATS)
+    b, _ = _recorded(cohort, _tcfg(3, loops=5), method="fedavg")
+    assert b.telemetry["fused_dispatch_ahead"] == 1
     for la, lb in zip(jax.tree_util.tree_leaves(a.final_params),
                       jax.tree_util.tree_leaves(b.final_params)):
         np.testing.assert_allclose(np.asarray(la), np.asarray(lb),
@@ -326,6 +342,119 @@ def test_fused_evaluates_at_chunk_boundaries(cohort):
     for i in (3, 4):                      # carried from the loop-2 eval
         assert res.records[i].auc_roc == res.records[2].auc_roc
     assert res.final.evaluated
+
+
+# ---------------------------------------------------------------------------
+# the chunk pipeline: chunk k+1 trains while chunk k is emitted
+# ---------------------------------------------------------------------------
+
+# 25 rounds at S=10 with varying bucketed P: three chunks, the last one
+# 5 real rounds padded to the run-constant horizon
+_PIPE = dict(loops=25, K=8, batch=32, sample_fraction=0.5,
+             dropout_rate=0.25)
+
+
+def _assert_same_records(a, b):
+    """Every field of every record but the wall clock, and the model."""
+    assert len(a.records) == len(b.records)
+    for ra, rb in zip(a.records, b.records):
+        for name in ("loop", "num_participants", "slots", "sparse_bytes",
+                     "dense_bytes", "upload_fraction", "epsilon",
+                     "auc_roc", "auc_pr", "evaluated", "hidden_sizes"):
+            assert getattr(ra, name) == getattr(rb, name), name
+    assert _params_bitwise_equal(a.final_params, b.final_params)
+
+
+def test_fused_dispatches_next_chunk_before_emission(cohort, monkeypatch):
+    """Chunk k+1 is dispatched before chunk k's deltas are pulled: the
+    device trains the next chunk while the host emits this one."""
+    orig = BatchedEngine.fused_scbf_chunk
+
+    def chunk(self, *args, **kw):
+        out = orig(self, *args, **kw)
+        obstrace.event("dispatched")
+        return out
+
+    monkeypatch.setattr(BatchedEngine, "fused_scbf_chunk", chunk)
+    _, rec = _recorded(cohort, _tcfg(10, **_PIPE))
+    sent = [e["ts"] for e in rec.events if e["ev"] == "dispatched"]
+    pulls = [e["ts"] - e["dur"] for e in rec.events
+             if e["ev"] == "span" and e["name"] == "emit_pull"]
+    assert len(sent) == len(pulls) == 3
+    for k in range(2):
+        assert sent[k + 1] <= pulls[k]
+
+
+def _donating(monkeypatch):
+    """Make donation real on the CPU: every chunk call deletes the
+    params it was given once it has been dispatched, as a backend that
+    donates them would, so any later read of them raises."""
+    orig = BatchedEngine.fused_scbf_chunk
+
+    def chunk(self, params, *args, **kw):
+        out = orig(self, params, *args, **kw)
+        jax.block_until_ready(out)
+        for leaf in jax.tree_util.tree_leaves(params):
+            leaf.delete()
+        return out
+
+    monkeypatch.setattr(BatchedEngine, "fused_scbf_chunk", chunk)
+
+
+_STORM = FaultConfig(enabled=True, seed=7, crash_rate=0.15,
+                     net_fail_rate=0.15, duplicate_rate=0.2,
+                     bitflip_rate=0.15, nan_rate=0.15, poison_rate=0.15)
+
+
+@pytest.mark.parametrize("faults", [False, True], ids=["plain", "faults"])
+def test_fused_pipeline_reads_no_donated_model(cohort, monkeypatch,
+                                               faults):
+    """With the chunk's input model deleted at dispatch, a multi-chunk
+    job with boundary evaluations — and one whose records run the fault
+    pipeline and the admission gate — finishes with the records the
+    undeleted job has: nothing reads a model after the next chunk
+    took it."""
+    kw = dict(_PIPE, loops=12, eval_every=1)
+    if faults:
+        kw.update(K=5, batch=64, sample_fraction=1.0, dropout_rate=0.0,
+                  faults=_STORM, max_update_norm=100.0)
+    cfg = _tcfg(5, **kw)
+    plain = run_federated(cohort, cfg, method="scbf", mlp_features=FEATS)
+    _donating(monkeypatch)
+    donated, rec = _recorded(cohort, cfg)
+    _assert_same_records(plain, donated)
+    assert donated.telemetry["fused_dispatch_ahead"] == 2
+    if faults:
+        assert donated.telemetry.get("payloads_rejected", 0) > 0
+
+
+def test_fused_pipeline_stays_off_while_pruning(cohort):
+    """SCBFwP's prune step after a chunk sets the next chunk's
+    keep-masks, so no prune-phase chunk runs ahead; the chunks after
+    pruning do, and the records stay the per-round mask run's."""
+    kw = dict(loops=10, scbf_kw=dict(prune=True, prune_impl="mask",
+                                     prune_rate=0.2, prune_total=0.4))
+    a = run_federated(cohort, _tcfg(1, **kw), method="scbf",
+                      mlp_features=FEATS)
+    b, rec = _recorded(cohort, _tcfg(3, **kw))
+    pruned = sum(1 for e in rec.events if e["ev"] == "prune")
+    chunks = sum(1 for e in rec.events
+                 if e["ev"] == "span" and e["name"] == "fused_chunk")
+    assert pruned >= 1 and chunks - pruned >= 2
+    # one-round prune chunks, then S-round chunks: only the latter run
+    # ahead, each into the next
+    assert b.telemetry["fused_dispatch_ahead"] == chunks - pruned - 1
+    _assert_trajectories_match(a, b)
+    assert [r.hidden_sizes for r in a.records] == \
+        [r.hidden_sizes for r in b.records]
+
+
+@pytest.mark.parametrize("method", ["scbf", "fedavg"])
+def test_fused_single_chunk_job_runs_nothing_ahead(cohort, method):
+    """A job of one chunk has no next chunk to dispatch early."""
+    res, _ = _recorded(cohort, _tcfg(5, loops=4), method=method)
+    assert res.telemetry["fused_dispatch_ahead"] == 0
+    assert len(res.records) == 4
 
 
 # ---------------------------------------------------------------------------

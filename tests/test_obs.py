@@ -370,9 +370,12 @@ def _inside(inner, outer):
 
 def test_recorded_fused_job_names_every_host_stretch(cohort):
     """Job set-up (with the cohort's copy), each chunk's planning, the
-    three parts of wire emission and the per-round records are spans,
-    nested as the layers are: wait, pull and encoding lie inside their
-    ``encode`` and add up to no more than it."""
+    wait for its program, the two parts of wire emission and the
+    per-round records are spans, nested as the driver's pipeline runs
+    them: the next chunk is planned inside this chunk's span before the
+    wait, the boundary evaluation lies between the wait and the
+    emission, and pull and encoding lie inside their ``encode`` and add
+    up to no more than it."""
     rec = Recorder()
     with recording(recorder=rec):
         run_federated(cohort, _tcfg(2, loops=4), method="scbf",
@@ -385,24 +388,30 @@ def test_recorded_fused_job_names_every_host_stretch(cohort):
     assert _inside(put, setup)
     chunks = sp["fused_chunk"]
     assert len(chunks) == len(sp["plan"]) == len(sp["encode"]) \
-        == len(sp["records"]) == 2
+        == len(sp["emit_wait"]) == len(sp["records"]) == 2
     assert setup[1] <= chunks[0][0] + _TOL
-    for chunk, plan, enc, recs in zip(chunks, sp["plan"], sp["encode"],
-                                      sp["records"]):
-        assert _inside(plan, chunk) and _inside(enc, chunk)
-        assert plan[1] <= enc[0] + _TOL
+    # both plans run inside the first chunk's span, before its wait:
+    # the second chunk is planned while the first one trains
+    for plan in sp["plan"]:
+        assert _inside(plan, chunks[0])
+        assert plan[1] <= sp["emit_wait"][0][0] + _TOL
+    for chunk, wait, enc, recs in zip(chunks, sp["emit_wait"],
+                                      sp["encode"], sp["records"]):
+        assert _inside(wait, chunk) and _inside(enc, chunk)
+        assert wait[1] <= enc[0] + _TOL
+        # the boundary evaluation reads the chunk's model between the
+        # wait and the next dispatch, before the emission
+        assert any(_inside(ev, chunk) and wait[1] <= ev[0] + _TOL
+                   and ev[1] <= enc[0] + _TOL for ev in sp["eval"])
         # the records follow their chunk, outside it
         assert chunk[1] <= recs[0] + _TOL
         parts = [next(p for p in sp[name] if _inside(p, enc))
-                 for name in ("emit_wait", "emit_pull", "wire_encode")]
-        for a, b in zip(parts, parts[1:]):
-            assert a[1] <= b[0] + _TOL
+                 for name in ("emit_pull", "wire_encode")]
+        assert parts[0][1] <= parts[1][0] + _TOL
         assert sum(p[2]["dur"] for p in parts) <= enc[2]["dur"] + _TOL
-        assert parts[1][2]["bytes"] > 0
-        assert parts[2][2]["slots"] == 2 * 5
-        # the chunk boundary's evaluation is nested in the records
-        assert any(_inside(ev, recs) for ev in sp["eval"])
-    for name in ("emit_wait", "emit_pull", "wire_encode"):
+        assert parts[0][2]["bytes"] > 0
+        assert parts[1][2]["slots"] == 2 * 5
+    for name in ("emit_pull", "wire_encode"):
         assert len(sp[name]) == 2
 
 
