@@ -14,13 +14,14 @@ One ``global loop``:
      set and push the pruned structure to all clients;
   5. evaluate AUC-ROC / AUC-PR on the test set.
 
-``run_federated`` is a thin driver over those three pluggable parts: it
-owns PRNG-key derivation (so engine choice never changes the random
+``run_federated`` refuses a job that cannot run (``_refuse``), sets it
+up (``Job``) and runs one of two round loops over those three parts.
+It owns PRNG-key derivation (so engine choice never changes the random
 stream), the lr schedule (precomputed as a host-side table — no
-per-loop device sync), differential privacy on the upload path
+per-loop device sync), the differential-privacy release ledger
 (optionally with subsampled-RDP amplification), and the per-loop
-records with the communication accounting used by EXPERIMENTS.md
-(§Paper-validation) and benchmarks/fig2.
+records (``Job.record``) with the communication accounting used by
+EXPERIMENTS.md (§Paper-validation) and benchmarks/fig2.
 
 With ``FedConfig.fuse_rounds = S > 1`` (sync mode, batched engine) the
 driver switches to the **fused round loop** (``_run_fused``): S rounds
@@ -172,13 +173,6 @@ def _partition(cohort: MedicalCohort, train_cfg: TrainConfig):
     raise ValueError(f"unknown partition {fed.partition!r}; iid|dirichlet")
 
 
-def _lr_schedule(train_cfg: TrainConfig):
-    if train_cfg.lr_schedule == "cosine":
-        return schedules.cosine_decay(train_cfg.learning_rate,
-                                      max(train_cfg.global_loops - 1, 1))
-    return schedules.constant(train_cfg.learning_rate)
-
-
 def _lr_table(train_cfg: TrainConfig) -> np.ndarray:
     """Host-side lr table for the whole run, one device dispatch total.
 
@@ -187,7 +181,9 @@ def _lr_table(train_cfg: TrainConfig) -> np.ndarray:
     that, and the same table slices into the fused path's (S,) lr
     array, so both paths read identical f32 values.
     """
-    fn = _lr_schedule(train_cfg)
+    lr = train_cfg.learning_rate
+    fn = schedules.cosine_decay(lr, max(train_cfg.global_loops - 1, 1)) \
+        if train_cfg.lr_schedule == "cosine" else schedules.constant(lr)
     steps = jnp.arange(max(train_cfg.global_loops, 1))
     return np.asarray(jax.vmap(fn)(steps), dtype=np.float32)
 
@@ -218,46 +214,15 @@ def _derive_round_keys(key, num_clients: int, part, P: int):
     return key, ckeys, skeys, dp_keys
 
 
-def run_federated(cohort: MedicalCohort,
-                  train_cfg: TrainConfig,
-                  method: str = "scbf",
-                  mlp_features: Optional[Tuple[int, ...]] = None,
-                  verbose: bool = False,
-                  engine: Optional[str] = None) -> RunResult:
-    """Run one federated experiment.
+def _refuse(train_cfg: TrainConfig, method: str,
+            engine: Optional[str]) -> bool:
+    """Raise ``ValueError`` for a job that cannot run as configured.
 
-    method: "scbf" | "fedavg", with pruning controlled by
-    ``train_cfg.scbf.prune`` (→ SCBFwP / FAwP).  ``engine`` overrides
-    ``train_cfg.fed.engine`` ("batched" vmapped cohort | "sequential"
-    reference loop); both consume the same PRNG stream, and for
-    equal-size shards (the paper's IID split) they produce identical
-    trajectories.  The batched engine buckets the per-round participant
-    count (``fed.bucket``) so varying P under sampling/dropout does not
-    recompile, and shards the bucketed cohort over a pod mesh when
-    ``fed.pods > 1`` (docs/FED_ENGINE.md).  ``fed.fuse_rounds > 1``
-    runs whole chunks of sync rounds as one device program with
-    on-device aggregation (bit-identical trajectory; evaluation at
-    chunk boundaries only), falling back to the per-round loop for
-    reshape-mode pruning, fedbuff, or the sequential engine —
-    mask-mode pruning (``scbf.prune_impl="mask"``) runs fused
-    first-class.  Rounds where every
-    sampled client drops out are skipped cleanly (no P=0 dispatch).
-    Ragged cohorts (Dirichlet) batch differently —
-    the padded engine runs ``n_max // B`` masked batches per epoch
-    while the sequential loop runs ``n_k // B`` — so there the engine
-    choice selects between two legitimate trainings, not two
-    implementations of one (docs/FED_ENGINE.md §Caveats).
+    Every check reads the configuration alone, so a refused job never
+    copies its cohort to the device.  Returns whether the job runs on
+    the fused round loop.
     """
-    # deferred: repro.fed modules import repro.core.* at module scope, so
-    # importing them here (not at module top) keeps repro.core importable
-    # from either direction
-    from repro.fed.clock import SimClock
-    from repro.fed.engine import make_engine
-    from repro.fed.faults import (FaultInjector, Resilience,
-                                  apply_payload_faults)
-    from repro.fed.scheduler import make_scheduler
-    from repro.fed.strategy import (AdmissionPolicy, RoundContribution,
-                                    admit_payloads, make_strategy)
+    from repro.fed.scheduler import cohort_size
 
     cfg: ScbfConfig = train_cfg.scbf
     fed = train_cfg.fed
@@ -313,172 +278,48 @@ def run_federated(cohort: MedicalCohort,
             "between emission and arrival, so the spilled indices "
             "would remap wrong — use deadline_action='drop' with "
             "pruning")
+    max_participants = cohort_size(fed, cfg.num_clients)
+    if fed.min_valid_participants > max_participants:
+        raise ValueError(
+            f"min_valid_participants={fed.min_valid_participants} can "
+            f"never be met: the scheduler samples at most "
+            f"{max_participants} clients per round — every "
+            "round would exhaust its retries and miss quorum")
 
-    # job set-up, from the model's initialisation to the round loop, is a
-    # span of its own so that profiles name it; ``cohort_put`` inside it
-    # is the engine build with the cohort's copy to the device
-    with obstrace.span("job_setup"):
-        feats = mlp_features or (cohort.num_features, 256, 64, 1)
-        key = jax.random.PRNGKey(train_cfg.seed)
-        key, init_key = jax.random.split(key)
-        params = init_mlp(feats, init_key)
-
-        clients = _partition(cohort, train_cfg)
-        with obstrace.span("cohort_put"):
-            eng = make_engine(engine or fed.engine, clients,
-                              train_cfg.local_batch_size,
-                              train_cfg.local_epochs,
-                              bucket=fed.bucket, pods=fed.pods)
-            # the engine holds the cohort now: free the host shards here,
-            # inside set-up, not when the job returns (about 20 ms for the
-            # paper's 215 MB cohort on a v5e host)
-            del clients
-        clock = SimClock(cfg.num_clients, fed.clock, seed=train_cfg.seed) \
-            if clock_on else None
-        scheduler = make_scheduler(fed, cfg.num_clients, train_cfg.seed,
-                                   clock=clock)
-        injector = FaultInjector(cfg.num_clients, fed.faults) \
-            if faults_on else None
-        # the admission gate arms whenever payloads can be hostile (fault
-        # injection) or a norm bound is configured; otherwise the strategies
-        # keep their zero-overhead fault-free hot path
-        policy = AdmissionPolicy(max_update_norm=fed.max_update_norm,
-                                 norm_action=fed.norm_action) \
-            if (faults_on or fed.max_update_norm > 0) else None
-        strategy = make_strategy(method, cfg, fed, policy=policy)
-        resil = Resilience(scheduler, clock, injector, fed)
-        if fed.min_valid_participants > 0 and \
-                fed.min_valid_participants > scheduler.max_participants:
+    if cfg.dp_noise_multiplier < 0:
+        raise ValueError(
+            f"dp_noise_multiplier must be >= 0, got "
+            f"{cfg.dp_noise_multiplier}: the DP gate is "
+            f"'dp_noise_multiplier > 0', so a negative value would "
+            f"silently run without DP while looking configured")
+    dp_on = method == "scbf" and cfg.dp_noise_multiplier > 0
+    if dp_on:
+        # fail fast on an unknown accountant or a classic-bound run
+        # outside its eps <= 1 domain, not after a full training loop
+        privacy.epsilon_for(cfg.dp_noise_multiplier, cfg.dp_delta,
+                            loops=1, accountant=cfg.dp_accountant)
+    if dp_on and cfg.dp_amplification:
+        if clock_on:
             raise ValueError(
-                f"min_valid_participants={fed.min_valid_participants} can "
-                f"never be met: the scheduler samples at most "
-                f"{scheduler.max_participants} clients per round — every "
-                "round would exhaust its retries and miss quorum")
-        state = strategy.init(params)
-        # fedbuff only: stale version snapshots (sync trains on the current
-        # params, so keeping the initial model alive would be pure waste)
-        history = {0: params} if fed.mode == "fedbuff" else None
-        # spill mode: round-keyed snapshots — a spilled client trains from
-        # the params of the round it was sampled in, delivered rounds later
-        round_history = {0: params} if spill_mode else None
-        # host-side lr table: one device dispatch for the whole run instead
-        # of a float() sync per loop, and the fused path's (S,) lr array
-        lrs = _lr_table(train_cfg)
-
-        if cfg.dp_noise_multiplier < 0:
+                "subsampled amplification assumes a uniform i.i.d. "
+                "per-round sample; the simulated clock restricts "
+                "sampling to currently-available clients (diurnal "
+                "churn), which is not one — refusing to report a "
+                "silently-wrong amplified ε")
+        if fed.mode == "fedbuff":
             raise ValueError(
-                f"dp_noise_multiplier must be >= 0, got "
-                f"{cfg.dp_noise_multiplier}: the DP gate is "
-                f"'dp_noise_multiplier > 0', so a negative value would "
-                f"silently run without DP while looking configured")
-        dp_on = method == "scbf" and cfg.dp_noise_multiplier > 0
-        if dp_on:
-            # fail fast on an unknown accountant or a classic-bound run
-            # outside its eps <= 1 domain, not after a full training loop
-            privacy.epsilon_for(cfg.dp_noise_multiplier, cfg.dp_delta,
-                                loops=1, accountant=cfg.dp_accountant)
-        amplify = dp_on and cfg.dp_amplification
-        amp_q = 1.0
-        if amplify:
-            if clock_on:
-                raise ValueError(
-                    "subsampled amplification assumes a uniform i.i.d. "
-                    "per-round sample; the simulated clock restricts "
-                    "sampling to currently-available clients (diurnal "
-                    "churn), which is not one — refusing to report a "
-                    "silently-wrong amplified ε")
-            if fed.mode == "fedbuff":
-                raise ValueError(
-                    "subsampled amplification assumes an i.i.d. per-round "
-                    "sample; fedbuff participation is not one — refusing to "
-                    "report a silently-wrong amplified ε")
-            if cfg.dp_accountant != "rdp":
-                raise ValueError("dp_amplification is an RDP analysis; it "
-                                 f"composes on the subsampled RDP curve, so "
-                                 f"dp_accountant={cfg.dp_accountant!r} cannot "
-                                 "back the reported ε — use 'rdp'")
-            # q from the scheduler's own cohort-size formula, so the
-            # reported amplification always matches the sampling performed
-            amp_q = min(1.0, scheduler.max_participants / cfg.num_clients)
-            privacy.amplified_epsilon_for(cfg.dp_noise_multiplier, amp_q,
-                                          cfg.dp_delta, rounds=1)  # fail fast
-        # ε composes per *release*, not per loop: under sampling, dropout or
-        # fedbuff a client uploads in only some rounds, so the spend is
-        # tracked per client and the worst (most-releasing) client reported.
-        # (The amplified curve instead composes over rounds — every round is
-        # one inclusion trial for every client.)
-        dp_releases = np.zeros(cfg.num_clients, dtype=np.int64)
-        pruner = None
-        if cfg.prune:
-            # fedbuff keeps full-geometry stale snapshots alive for its
-            # in-flight clients, so the one-shot mask-mode compaction must
-            # stay off there (mixed geometries could never stack)
-            pruner = pruning.Pruner(
-                params, cohort.x_val, prune_rate=cfg.prune_rate,
-                prune_total=cfg.prune_total, impl=cfg.prune_impl,
-                compact=cfg.prune_compact and fed.mode != "fedbuff")
-        result = RunResult(method=method + ("wp" if cfg.prune else ""),
-                           dp_delta=cfg.dp_delta if dp_on else None)
-
-        # ---- flight recorder (repro.obs, docs/OBSERVABILITY.md) ----
-        # device telemetry is the config's switch alone, so a recorded run
-        # compiles the programs an unrecorded one does; the compile-count
-        # watchdog only samples while recording (it touches jit caches,
-        # and un-recorded runs shouldn't)
-        collect = train_cfg.obs.device_metrics
-        counts0 = _compile_counts() if obstrace.get_recorder() is not None \
-            else None
-        obstrace.event(
-            "run_start", method=result.method, loops=train_cfg.global_loops,
-            clients=cfg.num_clients, engine=eng.name,
-            fuse_rounds=int(fed.fuse_rounds), mode=fed.mode,
-            dp_sigma=(cfg.dp_noise_multiplier * cfg.dp_clip_norm)
-            if dp_on else None,
-            prune=cfg.prune, prune_impl=cfg.prune_impl if cfg.prune else None)
-
-    def _epsilons(loop: int):
-        """(epsilon, epsilon_unamplified) for the record of ``loop``."""
-        if not dp_on:
-            return None, None
-        un = privacy.epsilon_for(cfg.dp_noise_multiplier, cfg.dp_delta,
-                                 loops=int(dp_releases.max()),
-                                 accountant=cfg.dp_accountant)
-        if amplify:
-            # both accountings are valid upper bounds — amplified
-            # composes over rounds, unamplified over per-client
-            # releases — so report the tighter of the two: under
-            # dropout with q ≈ 1 the release ledger can actually win
-            # (fewer releases than rounds, no amplification to offset)
-            amp = privacy.amplified_epsilon_for(
-                cfg.dp_noise_multiplier, amp_q, cfg.dp_delta,
-                rounds=loop + 1)
-            return min(amp, un), un
-        return un, None
-
-    init_params = params
-    known = {"roc": None, "pr": None}
-
-    def _metrics(params_now, do_eval: bool, nmasks=None):
-        """(auc_roc, auc_pr, evaluated) — last-known when not evaluating.
-
-        ``nmasks`` evaluates the masked model (mask-mode SCBFwP): the
-        pruned-and-masked network is the model the run is training, so
-        it is the one the records must score.  Before any evaluation
-        has happened the last-known model is the initial one, scored
-        lazily so the default config (eval_every=1, unfused) never pays
-        for it.
-        """
-        if do_eval:
-            known["roc"], known["pr"] = _evaluate(params_now,
-                                                  cohort.x_test,
-                                                  cohort.y_test,
-                                                  neuron_masks=nmasks)
-            return known["roc"], known["pr"], True
-        if known["roc"] is None:
-            known["roc"], known["pr"] = _evaluate(init_params,
-                                                  cohort.x_test,
-                                                  cohort.y_test)
-        return known["roc"], known["pr"], False
+                "subsampled amplification assumes an i.i.d. per-round "
+                "sample; fedbuff participation is not one — refusing to "
+                "report a silently-wrong amplified ε")
+        if cfg.dp_accountant != "rdp":
+            raise ValueError("dp_amplification is an RDP analysis; it "
+                             f"composes on the subsampled RDP curve, so "
+                             f"dp_accountant={cfg.dp_accountant!r} cannot "
+                             "back the reported ε — use 'rdp'")
+        privacy.amplified_epsilon_for(
+            cfg.dp_noise_multiplier,
+            min(1.0, max_participants / cfg.num_clients),
+            cfg.dp_delta, rounds=1)  # fail fast
 
     if int(fed.fuse_rounds) < 1:
         raise ValueError(f"fuse_rounds must be >= 1, got {fed.fuse_rounds}")
@@ -486,10 +327,11 @@ def run_federated(cohort: MedicalCohort,
     # feedback), static shapes (reshape pruning changes them mid-run;
     # MASK pruning keeps geometry run-constant and fuses first-class),
     # and the batched engine (there is no sequential program to fuse) —
-    # anything else falls back to the per-round loop below
+    # anything else falls back to the per-round loop
     use_fused = (int(fed.fuse_rounds) > 1 and fed.mode == "sync"
                  and (not cfg.prune or mask_prune)
-                 and eng.name == "batched" and not spill_mode)
+                 and (engine or fed.engine) == "batched"
+                 and not spill_mode)
     if use_fused:
         # the fused path aggregates on device from per-slot admit masks
         # decided at PLAN time (repro.fed.faults); a host-side admission
@@ -518,76 +360,344 @@ def run_federated(cohort: MedicalCohort,
                 "(max_update_norm > 0, norm_action='reject'); without "
                 "one the fused path would fold poison into the model — "
                 "arm the gate or use fuse_rounds=1")
-        _run_fused(cohort, train_cfg, method, eng, resil, state, key,
-                   lrs, dp_releases, result, _epsilons, _metrics, verbose,
-                   pruner, collect, injector=injector, policy=policy)
-        _finish_telemetry(result, counts0)
-        return result
+    return use_fused
 
-    prev_eps = 0.0
+
+class Job:
+    """One run's parts and the bookkeeping both round loops share.
+
+    Building it, around the initial model ``params``, is the job's
+    set-up; ``record`` turns each finished round of either loop into
+    its ``LoopRecord`` and ``round`` event.
+    """
+
+    def __init__(self, cohort: MedicalCohort, train_cfg: TrainConfig,
+                 method: str, params, engine: Optional[str],
+                 verbose: bool):
+        # deferred: repro.fed modules import repro.core.* at module
+        # scope, so importing them here (not at module top) keeps
+        # repro.core importable from either direction
+        from repro.fed.clock import SimClock
+        from repro.fed.engine import make_engine
+        from repro.fed.faults import FaultInjector, Resilience
+        from repro.fed.scheduler import make_scheduler
+        from repro.fed.strategy import AdmissionPolicy, make_strategy
+
+        cfg: ScbfConfig = train_cfg.scbf
+        fed = train_cfg.fed
+        faults_on = fed.faults.enabled
+        self.cohort, self.train_cfg, self.method = cohort, train_cfg, method
+        self.init_params, self.verbose = params, verbose
+        clients = _partition(cohort, train_cfg)
+        with obstrace.span("cohort_put"):
+            self.eng = make_engine(engine or fed.engine, clients,
+                                   train_cfg.local_batch_size,
+                                   train_cfg.local_epochs,
+                                   bucket=fed.bucket, pods=fed.pods)
+            # the engine holds the cohort now: free the host shards here,
+            # inside set-up, not when the job returns (about 20 ms for the
+            # paper's 215 MB cohort on a v5e host)
+            del clients
+        clock = SimClock(cfg.num_clients, fed.clock, seed=train_cfg.seed) \
+            if fed.clock.enabled else None
+        scheduler = make_scheduler(fed, cfg.num_clients, train_cfg.seed,
+                                   clock=clock)
+        injector = FaultInjector(cfg.num_clients, fed.faults) \
+            if faults_on else None
+        self.resil = Resilience(scheduler, clock, injector, fed)
+        # the admission gate arms whenever payloads can be hostile (fault
+        # injection) or a norm bound is configured; otherwise the strategies
+        # keep their zero-overhead fault-free hot path
+        policy = AdmissionPolicy(max_update_norm=fed.max_update_norm,
+                                 norm_action=fed.norm_action) \
+            if (faults_on or fed.max_update_norm > 0) else None
+        self.strategy = make_strategy(method, cfg, fed, policy=policy)
+        # host-side lr table: one device dispatch for the whole run instead
+        # of a float() sync per loop, and the fused path's (S,) lr array
+        self.lrs = _lr_table(train_cfg)
+        self.pruner = None
+        if cfg.prune:
+            # fedbuff keeps full-geometry stale snapshots alive for its
+            # in-flight clients, so the one-shot mask-mode compaction must
+            # stay off there (mixed geometries could never stack)
+            self.pruner = pruning.Pruner(
+                params, cohort.x_val, prune_rate=cfg.prune_rate,
+                prune_total=cfg.prune_total, impl=cfg.prune_impl,
+                compact=cfg.prune_compact and fed.mode != "fedbuff")
+        # device telemetry is the config's switch alone, so a recorded run
+        # compiles the programs an unrecorded one does
+        self.collect = train_cfg.obs.device_metrics
+        self.dp_on = method == "scbf" and cfg.dp_noise_multiplier > 0
+        self.result = RunResult(method=method + ("wp" if cfg.prune else ""),
+                                dp_delta=cfg.dp_delta if self.dp_on else None)
+        # ε composes per *release*, not per loop: under sampling, dropout or
+        # fedbuff a client uploads in only some rounds, so the spend is
+        # tracked per client and the worst (most-releasing) client reported.
+        # (The amplified curve instead composes over rounds — every round is
+        # one inclusion trial for every client.)
+        self.dp_releases = np.zeros(cfg.num_clients, dtype=np.int64)
+        self.known = None           # last-known (auc_roc, auc_pr)
+        self.prev_eps = 0.0         # ε of the last record
+
+        # ---- flight recorder (repro.obs, docs/OBSERVABILITY.md) ----
+        # the compile-count watchdog only samples while recording (it
+        # touches jit caches, and un-recorded runs shouldn't)
+        self.counts0 = _compile_counts() \
+            if obstrace.get_recorder() is not None else None
+        obstrace.event(
+            "run_start", method=self.result.method,
+            loops=train_cfg.global_loops, clients=cfg.num_clients,
+            engine=self.eng.name, fuse_rounds=int(fed.fuse_rounds),
+            mode=fed.mode, dp_sigma=(cfg.dp_noise_multiplier
+                                     * cfg.dp_clip_norm)
+            if self.dp_on else None,
+            prune=cfg.prune, prune_impl=cfg.prune_impl if cfg.prune else None)
+
+    def epsilons(self, loop: int):
+        """(epsilon, epsilon_unamplified) for the record of ``loop``."""
+        if not self.dp_on:
+            return None, None
+        cfg = self.train_cfg.scbf
+        un = privacy.epsilon_for(cfg.dp_noise_multiplier, cfg.dp_delta,
+                                 loops=int(self.dp_releases.max()),
+                                 accountant=cfg.dp_accountant)
+        if cfg.dp_amplification:
+            # both accountings are valid upper bounds — amplified
+            # composes over rounds, unamplified over per-client
+            # releases — so report the tighter of the two: under
+            # dropout with q ≈ 1 the release ledger can actually win
+            # (fewer releases than rounds, no amplification to offset).
+            # q from the scheduler's own cohort-size formula, so the
+            # reported amplification always matches the sampling
+            amp = privacy.amplified_epsilon_for(
+                cfg.dp_noise_multiplier,
+                min(1.0, self.resil.scheduler.max_participants
+                    / cfg.num_clients),
+                cfg.dp_delta, rounds=loop + 1)
+            return min(amp, un), un
+        return un, None
+
+    def metrics(self, params, do_eval: bool):
+        """(auc_roc, auc_pr, evaluated) — last-known when not evaluating.
+
+        Mask-mode SCBFwP scores the masked model: the pruned-and-masked
+        network is the model the run is training, so it is the one the
+        records must score.  Before any evaluation has happened the
+        last-known model is the initial one, scored lazily so the
+        default config (eval_every=1, unfused) never pays for it.
+        """
+        x, y = self.cohort.x_test, self.cohort.y_test
+        if do_eval:
+            self.known = _evaluate(
+                params, x, y, neuron_masks=self.pruner.masks
+                if self.pruner is not None else None)
+            return (*self.known, True)
+        if self.known is None:
+            self.known = _evaluate(self.init_params, x, y)
+        return (*self.known, False)
+
+    def upload(self, ar, payloads, expand=None):
+        """The round's SCBF uploads as the server receives them: through
+        the fault pipeline (client faults → seal → wire faults →
+        replays) when faults are injected, per-slot arrays to match."""
+        from repro.fed.faults import apply_payload_faults
+        from repro.fed.strategy import RoundContribution
+
+        plan, fed = ar.plan, self.train_cfg.fed
+        cl = np.asarray(plan.participants)
+        nx, stal = self.eng.counts[cl], np.asarray(plan.staleness)
+        wire_payloads = payloads
+        if self.resil.injector is not None and payloads:
+            wire_payloads, dup_src = apply_payload_faults(
+                payloads, cl, ar.corrupt, ar.duplicated, plan.round_index,
+                ar.attempts - 1, fed.faults, fed.max_update_norm)
+            if dup_src:
+                nx, stal, cl = (np.concatenate([a, a[dup_src]])
+                                for a in (nx, stal, cl))
+        return RoundContribution(num_examples=nx, staleness=stal,
+                                 payloads=wire_payloads, clients=cl,
+                                 expand=expand)
+
+    def prune(self, state, loop: int):
+        """SCBFwP / FAwP after a round: a pruning step while the budget
+        lasts, then (mask mode) the one-shot compaction.  Returns the
+        server state to go on from."""
+        pruner = self.pruner
+        if pruner is None:
+            return state
+        if pruner.active:
+            # reshape: returns the compacted pytree; mask: updates the
+            # keep-masks in place and returns params unchanged
+            state = dataclasses.replace(state,
+                                        params=pruner.step(state.params))
+            obstrace.event("prune", loop=loop,
+                           hidden=list(pruner.hidden_sizes()))
+        if pruner.should_compact:
+            # mask mode, budget exhausted: one-shot compaction
+            state = dataclasses.replace(state,
+                                        params=pruner.compact(state.params))
+            obstrace.event("compact", loop=loop,
+                           hidden=list(pruner.hidden_sizes()))
+        return state
+
+    def record(self, ar, model, payloads, wire_payloads, stats, dm,
+               quality, wall: float, slots: int,
+               amortized: bool = False) -> None:
+        """Charge a finished round's DP releases, account its upload of
+        ``model`` (whole, for FedAvg) and ``wire_payloads``, and record
+        it; ``amortized`` marks ``wall`` as a chunk's share."""
+        plan = ar.plan
+        loop, P = plan.round_index, plan.num_participants
+        dm = dm if P else None
+        size = sum(int(np.prod(l["w"].shape)) + int(l["b"].shape[0])
+                   for l in model)
+        if self.method == "scbf":
+            # aborted quorum attempts trained and uploaded before the
+            # server discarded them — their privacy spend is real and
+            # must never be under-reported.  Each aborted attempt is a
+            # DISTINCT (simulated) upload with fresh keys, so a client
+            # charged twice here released twice, not one release
+            # double-counted — charging them is conservative in exactly
+            # the direction DP accounting must err.
+            released = [a for a in ar.aborted_arrivers if a.size]
+            if P:
+                released.append(plan.participants)
+            for clients in released:
+                self.dp_releases[np.asarray(clients)] += 1
+            up_frac = float(np.mean([s.upload_fraction
+                                     for s in stats])) if stats else 0.0
+            # measured bytes of the encoded payloads (single source of
+            # truth: repro.comm.wire), not a mask-count model
+            sparse_bytes = int(sum(p.nbytes for p in wire_payloads))
+            dense_bytes = int(sum(p.dense_nbytes for p in payloads))
+        else:
+            up_frac = 1.0 if P else 0.0
+            dense_bytes = size * 4 * P
+            sparse_bytes = dense_bytes
+        if self.pruner is not None:
+            # effective model: identical whether neurons are masked,
+            # compacted, or (reshape mode) physically gone
+            n_params = self.pruner.effective_param_count(model)
+            hidden = self.pruner.hidden_sizes()
+        else:
+            n_params, hidden = size, tuple(pruning.hidden_sizes(model))
+        roc, pr, evaluated = quality
+        eps, eps_un = self.epsilons(loop)
+        rec = LoopRecord(
+            loop=loop, auc_roc=roc, auc_pr=pr, upload_fraction=up_frac,
+            sparse_bytes=sparse_bytes, dense_bytes=dense_bytes,
+            wall_time=wall,
+            flops_proxy=float(n_params) * self.cohort.x_train.shape[0],
+            hidden_sizes=hidden, num_participants=P, slots=slots,
+            epsilon=eps, evaluated=evaluated, epsilon_unamplified=eps_un,
+            train_loss=dm.get("train_loss") if dm else None,
+            wall_is_amortized=amortized)
+        self.result.records.append(rec)
+        obstrace.event("round", **_round_event_fields(
+            rec, plan, self.pruner, dm,
+            None if eps is None else eps - self.prev_eps))
+        self.prev_eps = eps if eps is not None else 0.0
+        if self.verbose:
+            print(f"[{self.result.method}] loop {loop:02d} "
+                  f"auc_roc={roc:.4f} auc_pr={pr:.4f} "
+                  f"upload={up_frac:.2%} hidden={hidden} "
+                  f"clients={P} t={wall:.2f}s"
+                  + (" (metrics carried)" if amortized and not evaluated
+                     else ""))
+
+
+def run_federated(cohort: MedicalCohort,
+                  train_cfg: TrainConfig,
+                  method: str = "scbf",
+                  mlp_features: Optional[Tuple[int, ...]] = None,
+                  verbose: bool = False,
+                  engine: Optional[str] = None) -> RunResult:
+    """Run one federated experiment.
+
+    method: "scbf" | "fedavg", with pruning controlled by
+    ``train_cfg.scbf.prune`` (→ SCBFwP / FAwP).  ``engine`` overrides
+    ``train_cfg.fed.engine`` ("batched" vmapped cohort | "sequential"
+    reference loop); both consume the same PRNG stream, and for
+    equal-size shards (the paper's IID split) they produce identical
+    trajectories.  The batched engine buckets the per-round participant
+    count (``fed.bucket``) so varying P under sampling/dropout does not
+    recompile, and shards the bucketed cohort over a pod mesh when
+    ``fed.pods > 1`` (docs/FED_ENGINE.md).  ``fed.fuse_rounds > 1``
+    runs whole chunks of sync rounds as one device program with
+    on-device aggregation (bit-identical trajectory; evaluation at
+    chunk boundaries only), falling back to the per-round loop for
+    reshape-mode pruning, fedbuff, or the sequential engine —
+    mask-mode pruning (``scbf.prune_impl="mask"``) runs fused
+    first-class.  Rounds where every
+    sampled client drops out are skipped cleanly (no P=0 dispatch).
+    Ragged cohorts (Dirichlet) batch differently —
+    the padded engine runs ``n_max // B`` masked batches per epoch
+    while the sequential loop runs ``n_k // B`` — so there the engine
+    choice selects between two legitimate trainings, not two
+    implementations of one (docs/FED_ENGINE.md §Caveats).
+    """
+    use_fused = _refuse(train_cfg, method, engine)
+    # job set-up, from the model's initialisation to the round loop, is a
+    # span of its own so that profiles name it; ``cohort_put`` inside it
+    # is the engine build with the cohort's copy to the device
+    with obstrace.span("job_setup"):
+        feats = mlp_features or (cohort.num_features, 256, 64, 1)
+        key = jax.random.PRNGKey(train_cfg.seed)
+        key, init_key = jax.random.split(key)
+        params = init_mlp(feats, init_key)
+        job = Job(cohort, train_cfg, method, params, engine, verbose)
+        state = job.strategy.init(params)
+    (_run_fused if use_fused else _run_per_round)(job, state, key)
+    _finish_telemetry(job.result, job.counts0)
+    return job.result
+
+
+def _run_per_round(job: Job, state, key) -> None:
+    """The per-round loop: one engine call and one aggregation a round,
+    for every job the fused loop does not run (``fuse_rounds=1``,
+    reshape-mode pruning, fedbuff, deadline spilling, sequential)."""
+    from repro.fed.strategy import RoundContribution
+
+    train_cfg, eng, pruner = job.train_cfg, job.eng, job.pruner
+    cfg, fed = train_cfg.scbf, train_cfg.fed
+    scheduler = job.resil.scheduler
+    fedbuff = fed.mode == "fedbuff"
+    spill_mode = fed.clock.enabled and fed.clock.deadline_action == "spill"
+    # stale snapshots: fedbuff keys them by server version, spill mode by
+    # round — a spilled client trains from the params of the round it
+    # was sampled in, delivered rounds later (sync trains on the current
+    # params, so keeping the initial model alive would be pure waste)
+    history = {0: state.params} if fedbuff or spill_mode else None
     for loop in range(train_cfg.global_loops):
-        # one span is the loop's single wall-clock source: the region it
-        # covers (schedule → train → aggregate → prune) is exactly what
-        # the old hand-rolled perf_counter pair measured — evaluation
-        # stays outside, as before
+        # one span is the round's single wall-clock source: schedule →
+        # train → aggregate → prune, with evaluation outside it
         with obstrace.span("round", loop=loop) as sp:
-            lr = float(lrs[loop])
-            ar = resil.plan_round(loop, state.version)
+            lr = float(job.lrs[loop])
+            ar = job.resil.plan_round(loop, state.version)
             plan = ar.plan
             part = plan.participants
             P = plan.num_participants
-            if method == "scbf":
-                # aborted quorum attempts trained and uploaded before
-                # the server discarded them — their privacy spend is
-                # real and must never be under-reported.  Each aborted
-                # attempt is a DISTINCT (simulated) upload, so two
-                # increments on this path are two releases, not one
-                # double-counted — charging them is conservative in
-                # exactly the direction DP accounting must err.
-                for aborted in ar.aborted_arrivers:
-                    if aborted.size:
-                        dp_releases[np.asarray(aborted)] += 1  # privlint: disable=PL004
-
             key, ckeys, skeys, dp_keys = _derive_round_keys(
                 key, cfg.num_clients, part, P)
 
             payloads, stats, dm = [], [], None
             wire_payloads = []
             if P:
-                if fed.mode == "fedbuff":
-                    params_for = [history[state.version - int(tau)]
+                params_for = state.params
+                if history is not None:
+                    # staleness = versions behind (fedbuff) or rounds in
+                    # flight (spilled arrivals)
+                    now = state.version if fedbuff else loop
+                    params_for = [history[now - int(tau)]
                                   for tau in plan.staleness]
-                elif spill_mode:
-                    # spilled arrivals trained from the round they were
-                    # sampled in (staleness = rounds in flight)
-                    params_for = [round_history[loop - int(tau)]
-                                  for tau in plan.staleness]
-                else:
-                    params_for = state.params
-                if method == "scbf":
+                if job.method == "scbf":
                     nmasks = pruner.masks if pruner is not None else None
                     keep_eff = pruner.emission_keep if pruner is not None \
                         else None
                     out = eng.scbf_round(
                         params_for, part, lr, ckeys, skeys, dp_keys, cfg,
-                        nmasks=nmasks, keep=keep_eff, collect=collect)
-                    (payloads, stats, dm) = out if collect else \
-                        (out[0], out[1], None)
-                    dp_releases[np.asarray(part)] += 1
-                    wire_payloads = payloads
-                    nx = eng.counts[np.asarray(part)]
-                    stal = np.asarray(plan.staleness)
-                    cl = np.asarray(part)
-                    if injector is not None and payloads:
-                        # client faults → seal → wire faults → replays
-                        wire_payloads, dup_src = apply_payload_faults(
-                            payloads, cl, ar.corrupt, ar.duplicated,
-                            loop, ar.attempts - 1, fed.faults,
-                            fed.max_update_norm)
-                        if dup_src:
-                            nx = np.concatenate([nx, nx[dup_src]])
-                            stal = np.concatenate([stal, stal[dup_src]])
-                            cl = np.concatenate([cl, cl[dup_src]])
+                        nmasks=nmasks, keep=keep_eff, collect=job.collect)
+                    payloads, stats, dm = out if job.collect else (*out, None)
                     # mask mode ships effective-geometry payloads whose
                     # checksums seal the wire bytes; the strategy admits
                     # on those and expands the survivors to the server's
@@ -597,124 +707,48 @@ def run_federated(cohort: MedicalCohort,
                         expand = (lambda ps, _k=keep_eff,
                                   _ref=state.params:
                                   pruning.expand_payloads(ps, _k, _ref))
-                    contrib = RoundContribution(
-                        num_examples=nx, staleness=stal,
-                        payloads=wire_payloads, clients=cl,
-                        expand=expand)
+                    contrib = job.upload(ar, payloads, expand)
+                    wire_payloads = contrib.payloads
                 else:
                     out = eng.fedavg_round(params_for, part, lr, ckeys,
-                                           collect=collect)
-                    (client_params, counts, dm) = out if collect else \
+                                           collect=job.collect)
+                    (client_params, counts, dm) = out if job.collect else \
                         (out[0], out[1], None)
                     contrib = RoundContribution(
                         num_examples=counts, staleness=plan.staleness,
                         client_params=client_params,
                         clients=np.asarray(part))
                 if ar.quorum_ok:
-                    state = strategy.aggregate(state, contrib)
+                    state = job.strategy.aggregate(state, contrib)
                 # terminal quorum miss: the cohort trained and uploaded,
                 # but the server refuses to step on a sub-quorum round
                 # (the planner already emitted the quorum_miss event)
-            params = state.params
-            if fed.mode == "fedbuff":
-                history[state.version] = params
-                live = scheduler.referenced_versions() | {state.version}
-                history = {v: p for v, p in history.items() if v in live}
-            elif spill_mode:
-                round_history[loop + 1] = params
-                live = scheduler.referenced_rounds() | {loop + 1}
-                round_history = {r: p for r, p in round_history.items()
-                                 if r in live}
+            trained = state.params
+            if history is not None:
+                at, live = (state.version, scheduler.referenced_versions()) \
+                    if fedbuff else (loop + 1, scheduler.referenced_rounds())
+                history[at] = trained
+                history = {k: p for k, p in history.items()
+                           if k in live or k == at}
+            state = job.prune(state, loop)
 
-            # ---- communication accounting ----
-            if method == "scbf":
-                up_frac = float(np.mean([s.upload_fraction
-                                         for s in stats])) if stats else 0.0
-                # measured bytes of the encoded payloads (single source
-                # of truth: repro.comm.wire), not a mask-count model —
-                # wire_payloads includes replayed duplicates: bytes that
-                # really crossed the network
-                sparse_bytes = int(np.sum([p.nbytes
-                                           for p in wire_payloads])) \
-                    if wire_payloads else 0
-                dense_bytes = int(np.sum([p.dense_nbytes
-                                          for p in payloads])) \
-                    if payloads else 0
-            else:
-                total = sum(int(np.prod(l["w"].shape))
-                            + int(l["b"].shape[0]) for l in params)
-                up_frac = 1.0 if P else 0.0
-                dense_bytes = total * 4 * P
-                sparse_bytes = dense_bytes
-
-            # ---- pruning (SCBFwP / FAwP) ----
-            if pruner is not None and pruner.active:
-                # reshape: returns the compacted pytree; mask: updates
-                # the keep-masks in place and returns params unchanged
-                params = pruner.step(params)
-                state = dataclasses.replace(state, params=params)
-                obstrace.event("prune", loop=loop,
-                               hidden=list(pruner.hidden_sizes()))
-            if pruner is not None and pruner.should_compact:
-                # mask mode, budget exhausted: one-shot compaction
-                params = pruner.compact(params)
-                state = dataclasses.replace(state, params=params)
-                obstrace.event("compact", loop=loop,
-                               hidden=list(pruner.hidden_sizes()))
-
-        wall = sp.elapsed
-        roc, pr, evaluated = _metrics(
-            params, _should_eval(loop, train_cfg.global_loops,
-                                 train_cfg.eval_every),
-            pruner.masks if pruner is not None else None)
-        eps, eps_un = _epsilons(loop)
-        if pruner is not None:
-            # effective model: identical whether neurons are masked,
-            # compacted, or (reshape mode) physically gone
-            n_params = pruner.effective_param_count(params)
-            hidden = pruner.hidden_sizes()
-        else:
-            n_params = sum(int(np.prod(l["w"].shape)) + int(l["b"].shape[0])
-                           for l in params)
-            hidden = tuple(pruning.hidden_sizes(params))
-        rec = LoopRecord(
-            loop=loop, auc_roc=roc, auc_pr=pr,
-            upload_fraction=up_frac,
-            sparse_bytes=sparse_bytes, dense_bytes=dense_bytes,
-            wall_time=wall,
-            flops_proxy=float(n_params) * cohort.x_train.shape[0],
-            hidden_sizes=hidden,
-            num_participants=P, slots=eng.round_slots(P),
-            epsilon=eps, evaluated=evaluated, epsilon_unamplified=eps_un,
-            train_loss=dm.get("train_loss") if dm else None)
-        result.records.append(rec)
+        quality = job.metrics(state.params, _should_eval(
+            loop, train_cfg.global_loops, train_cfg.eval_every))
         if train_cfg.debug_checks:
             # host-side chunk-boundary assertions on already-offloaded
             # values; the traced program is identical either way
-            obschecks.verify_round(params, dm, where=f"loop {loop}")
-        obstrace.event("round", **_round_event_fields(
-            rec, plan, pruner, dm, eps_step=(eps - prev_eps)
-            if eps is not None else None))
-        prev_eps = eps if eps is not None else 0.0
-        if verbose:
-            print(f"[{result.method}] loop {loop:02d} "
-                  f"auc_roc={roc:.4f} auc_pr={pr:.4f} "
-                  f"upload={up_frac:.2%} hidden={rec.hidden_sizes} "
-                  f"clients={P} t={wall:.2f}s")
-    result.final_params = params
-    _finish_telemetry(result, counts0)
-    return result
+            obschecks.verify_round(state.params, dm, where=f"loop {loop}")
+        job.record(ar, trained, payloads, wire_payloads, stats, dm,
+                   quality, sp.elapsed, eng.round_slots(P))
+    job.result.final_params = state.params
 
 
 def _round_event_fields(rec: LoopRecord, plan, pruner, dm,
-                        eps_step=None) -> dict:
-    """The ``round`` event's field dict (docs/OBSERVABILITY.md schema).
-
-    One builder for both loop shapes so the per-round and fused paths
-    emit identical event structure: LoopRecord scalars + scheduler
-    telemetry (sampled/dropped/stragglers/staleness) + keep-mask density
-    + the on-device metrics dict when collection was on.
-    """
+                        eps_step) -> dict:
+    """The ``round`` event's field dict (docs/OBSERVABILITY.md schema):
+    LoopRecord scalars + scheduler telemetry (sampled/dropped/stragglers/
+    staleness) + keep-mask density + the on-device metrics dict when
+    collection was on."""
     out = {
         "loop": rec.loop, "participants": rec.num_participants,
         "slots": rec.slots,
@@ -726,15 +760,12 @@ def _round_event_fields(rec: LoopRecord, plan, pruner, dm,
         "evaluated": rec.evaluated,
     }
     if rec.epsilon is not None:
-        out["epsilon"] = rec.epsilon
-        if eps_step is not None:
-            out["epsilon_step"] = eps_step
+        out["epsilon"], out["epsilon_step"] = rec.epsilon, eps_step
     if pruner is not None:
         out["keep_density"] = round(
             sum(pruner.hidden_sizes()) / max(pruner.original_hidden, 1),
             6)
-    if plan is not None and hasattr(plan, "telemetry"):
-        out.update(plan.telemetry())
+    out.update(plan.telemetry())
     if dm:
         for k in ("train_loss", "selected", "codec_bytes"):
             if dm.get(k) is not None:
@@ -752,11 +783,7 @@ class _Chunk(NamedTuple):
     version: int        # the server version after the chunk
 
 
-def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
-               eng, resil, state, key, lrs: np.ndarray,
-               dp_releases: np.ndarray, result: RunResult,
-               _epsilons, _metrics, verbose: bool, pruner=None,
-               collect: bool = False, injector=None, policy=None) -> None:
+def _run_fused(job: Job, state, key) -> None:
     """The fused round loop: S sync rounds per device program.
 
     Each chunk is pre-planned into static (S, B) participant/validity
@@ -796,33 +823,20 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
     masked full geometry otherwise).
     """
     from repro.fed.cohort import fused_chunk_len
-    from repro.fed.faults import apply_payload_faults
-    from repro.fed.strategy import RoundContribution, admit_payloads
+    from repro.fed.strategy import admit_payloads
 
-    cfg: ScbfConfig = train_cfg.scbf
-    fed = train_cfg.fed
-    scheduler = resil.scheduler
-    S = int(fed.fuse_rounds)
-    B = eng.fused_num_slots(scheduler.max_participants)
+    train_cfg, method, eng, pruner = job.train_cfg, job.method, job.eng, \
+        job.pruner
+    cfg, resil, collect = train_cfg.scbf, job.resil, job.collect
+    S = int(train_cfg.fed.fuse_rounds)
+    B = eng.fused_num_slots(resil.scheduler.max_participants)
     total_loops = train_cfg.global_loops
-
-    def _model_stats():
-        """(n_params, hidden_sizes) of the current effective model."""
-        if pruner is not None:
-            return (pruner.effective_param_count(state.params),
-                    pruner.hidden_sizes())
-        n = sum(int(np.prod(l["w"].shape)) + int(l["b"].shape[0])
-                for l in state.params)
-        return n, tuple(pruning.hidden_sizes(state.params))
-
-    def _prune_active() -> bool:
-        return pruner is not None and pruner.active
 
     def _plan(loop0: int) -> _Chunk:
         """Plan the chunk that starts at ``loop0`` from the server state
         after the chunk before it (its version, its model's shapes)."""
         nonlocal key
-        prune_active = _prune_active()
+        prune_active = pruner is not None and pruner.active
         chunk = fused_chunk_len(total_loops - loop0, S, prune_active)
         with obstrace.span("plan", rounds=chunk):
             # the resilient planner replaces plan_horizon: same
@@ -832,19 +846,15 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
             # admission verdicts fold into the static (S, B) admit mask
             ars = [resil.plan_round(loop0 + i, state.version)
                    for i in range(chunk)]
-            parts, cks, sks, dks, wts = [], [], [], [], []
-            for ar in ars:
-                part = ar.plan.participants
+            parts = [ar.plan.participants for ar in ars]
+            key_rows, wts = [], []
+            for part, ar in zip(parts, ars):
                 P = ar.plan.num_participants
                 # _derive_round_keys is the single key-stream contract,
                 # so the fused pre-planner consumes EXACTLY what the
                 # per-round loop would have
-                key, ck, sk, dk = _derive_round_keys(key, cfg.num_clients,
-                                                     part, P)
-                cks.append(np.asarray(ck))
-                sks.append(np.asarray(sk))
-                dks.append(np.asarray(dk))
-                parts.append(part)
+                key, *ks = _derive_round_keys(key, cfg.num_clients, part, P)
+                key_rows.append([np.asarray(k) for k in ks])
                 if method == "fedavg":
                     if P and ar.quorum_ok:
                         n = eng.counts[np.asarray(part)].astype(np.float64)
@@ -860,7 +870,7 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
             admits = [ar.admit_mask() for ar in ars] if resil.active \
                 else None
             fplan = eng.prepare_fused_plan(
-                parts, lrs[loop0:loop0 + chunk], cks, sks, dks,
+                parts, job.lrs[loop0:loop0 + chunk], *zip(*key_rows),
                 horizon=1 if prune_active else S, num_slots=B,
                 weights=wts if method == "fedavg" else None,
                 eff_sizes=eff, admit=admits)
@@ -901,22 +911,21 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
         # it NOW, before the chunk call donates the initial params'
         # buffers on backends that support donation (a lazy evaluation
         # afterwards would read deleted arrays)
-        _metrics(state.params, True,
-                 pruner.masks if pruner is not None else None)
+        job.metrics(state.params, True)
 
     # the key stream is split on the host's CPU backend (threefry gives
     # the same bits on every backend), so planning chunk k+1 does not
     # queue behind chunk k's program: a device runs its programs in order
     key = jax.device_put(key, jax.devices("cpu")[0])
     loop0 = 0
-    prev_eps = 0.0
     cur = out = None       # the chunk dispatched ahead, and its outputs
     # chunks dispatched before their predecessor's emission (present,
     # at 0, on every recorded fused run)
     obstrace.count("fused_dispatch_ahead", 0)
     while loop0 < total_loops:
         # a chunk planned ahead was planned with this same pruner state
-        chunk = fused_chunk_len(total_loops - loop0, S, _prune_active())
+        chunk = fused_chunk_len(total_loops - loop0, S,
+                                pruner is not None and pruner.active)
         nxt = nxt_out = None
         # the chunk span (its wall / rounds is each record's wall time)
         # covers (when nothing ran ahead) this chunk's plan and dispatch
@@ -944,33 +953,20 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
                 # host-side, on the values the chunk already offloaded
                 obschecks.verify_round(state.params, round_metrics,
                                        where=f"chunk@loop {loop0}")
-            if cur.prune_active:
-                # chunk boundary == per-round cadence while pruning
-                # (chunks are 1 round long): APoZ on device, mask update
-                # on host
-                pruner.step(state.params)
-                obstrace.event("prune", loop=loop0,
-                               hidden=list(pruner.hidden_sizes()))
-                if pruner.should_compact:
-                    state = dataclasses.replace(
-                        state, params=pruner.compact(state.params))
-                    obstrace.event("compact", loop=loop0,
-                                   hidden=list(pruner.hidden_sizes()))
-            n_params, hidden = _model_stats()
-            quality = [_metrics(state.params,
-                                r == chunk - 1 and _should_eval(
-                                    loop0 + r, total_loops,
-                                    train_cfg.eval_every),
-                                pruner.masks if pruner is not None
-                                else None)
+            # chunk boundary == per-round cadence while pruning (chunks
+            # are 1 round long): APoZ on device, mask update on host
+            state = job.prune(state, loop0)
+            quality = [job.metrics(state.params,
+                                   r == chunk - 1 and _should_eval(
+                                       loop0 + r, total_loops,
+                                       train_cfg.eval_every))
                        for r in range(chunk)]
             if ahead:
                 nxt_out = _dispatch(nxt)
                 obstrace.count("fused_dispatch_ahead")
             if method == "scbf":
-                masked_s, masks_s = stacks
-                emitted = eng.emit_fused_payloads(masked_s, masks_s,
-                                                  cur.fplan, keep=cur.keep)
+                emitted = eng.emit_fused_payloads(*stacks, cur.fplan,
+                                                  keep=cur.keep)
             else:
                 emitted = [([], [])] * chunk
         wall_each = sp.elapsed / chunk
@@ -978,93 +974,36 @@ def _run_fused(cohort: MedicalCohort, train_cfg: TrainConfig, method: str,
         # the chunk's per-round records, while the next chunk runs
         with obstrace.span("records", loop0=loop0, rounds=chunk):
             for r, ar in enumerate(cur.ars):
-                plan = ar.plan
-                loop = loop0 + r
-                P = plan.num_participants
                 payloads, stats = emitted[r]
-                dm = round_metrics[r] if round_metrics is not None else None
-                if method == "scbf":
-                    # aborted quorum attempts are distinct uploads (fresh
-                    # keys each attempt): two increments = two releases
-                    for aborted in ar.aborted_arrivers:
-                        if aborted.size:
-                            dp_releases[np.asarray(aborted)] += 1  # privlint: disable=PL004
-                    wire_payloads = payloads
-                    if injector is not None and payloads:
-                        # re-run the fault pipeline + the REAL admission
-                        # gate on the emitted wire artifacts: events/counts
-                        # match the per-round path, and the verdicts are
-                        # checked against the plan the device already
-                        # folded in (any divergence is a hard error, never
-                        # a silent one); the gate reads the server's
-                        # nonces, never its (donated) model
-                        cl = np.asarray(plan.participants)
-                        wire_payloads, dup_src = apply_payload_faults(
-                            payloads, cl, ar.corrupt, ar.duplicated, loop,
-                            ar.attempts - 1, fed.faults, fed.max_update_norm)
-                        if ar.quorum_ok:
-                            if dup_src:
-                                cl = np.concatenate([cl, cl[dup_src]])
-                            gate_contrib = RoundContribution(
-                                num_examples=np.zeros(len(wire_payloads),
-                                                      np.int64),
-                                staleness=np.zeros(len(wire_payloads),
-                                                   np.int64),
-                                payloads=wire_payloads, clients=cl)
-                            _, kept_idx = admit_payloads(state, gate_contrib,
-                                                         policy)
-                            planned = {i for i in range(P)
-                                       if not ar.will_reject[i]}
-                            if set(kept_idx) != planned:
-                                raise RuntimeError(
-                                    f"fused admission mismatch at loop "
-                                    f"{loop}: the device folded slots "
-                                    f"{sorted(planned)} but the admission "
-                                    f"gate admitted {sorted(kept_idx)} — "
-                                    "an update failed a gate the planner "
-                                    "could not predict (e.g. a natural "
-                                    "nonfinite or norm violation); rerun "
-                                    "with fuse_rounds=1")
-                    up_frac = float(np.mean(
-                        [s.upload_fraction for s in stats])) \
-                        if stats else 0.0
-                    sparse_bytes = int(np.sum([p.nbytes
-                                               for p in wire_payloads])) \
-                        if wire_payloads else 0
-                    dense_bytes = int(np.sum([p.dense_nbytes
-                                              for p in payloads])) \
-                        if payloads else 0
-                    if P:
-                        dp_releases[np.asarray(plan.participants)] += 1
-                else:
-                    up_frac = 1.0 if P else 0.0
-                    dense_bytes = n_params * 4 * P
-                    sparse_bytes = dense_bytes
-                roc, pr, evaluated = quality[r]
-                eps, eps_un = _epsilons(loop)
-                rec = LoopRecord(
-                    loop=loop, auc_roc=roc, auc_pr=pr,
-                    upload_fraction=up_frac,
-                    sparse_bytes=sparse_bytes, dense_bytes=dense_bytes,
-                    wall_time=wall_each,
-                    flops_proxy=float(n_params) * cohort.x_train.shape[0],
-                    hidden_sizes=hidden, num_participants=P, slots=B,
-                    epsilon=eps, evaluated=evaluated,
-                    epsilon_unamplified=eps_un,
-                    train_loss=(dm or {}).get("train_loss")
-                    if (dm and P) else None,
-                    wall_is_amortized=True)
-                result.records.append(rec)
-                obstrace.event("round", **_round_event_fields(
-                    rec, plan, pruner, dm if P else None,
-                    eps_step=(eps - prev_eps) if eps is not None else None))
-                prev_eps = eps if eps is not None else 0.0
-                if verbose:
-                    print(f"[{result.method}] loop {loop:02d} "
-                          f"auc_roc={roc:.4f} auc_pr={pr:.4f} "
-                          f"upload={up_frac:.2%} hidden={rec.hidden_sizes} "
-                          f"clients={P} t={wall_each:.2f}s"
-                          + ("" if evaluated else " (metrics carried)"))
+                wire_payloads = payloads
+                if resil.injector is not None and payloads:
+                    # re-run the fault pipeline + the REAL admission gate
+                    # on the emitted wire artifacts: events/counts match
+                    # the per-round path, and the verdicts are checked
+                    # against the plan the device already folded in (any
+                    # divergence is a hard error, never a silent one);
+                    # the gate reads the server's nonces, never its
+                    # (donated) model
+                    contrib = job.upload(ar, payloads)
+                    wire_payloads = contrib.payloads
+                    if ar.quorum_ok:
+                        _, kept_idx = admit_payloads(state, contrib,
+                                                     job.strategy.policy)
+                        planned = {i for i, rej in enumerate(ar.will_reject)
+                                   if not rej}
+                        if set(kept_idx) != planned:
+                            raise RuntimeError(
+                                f"fused admission mismatch at loop "
+                                f"{loop0 + r}: the device folded slots "
+                                f"{sorted(planned)} but the admission "
+                                f"gate admitted {sorted(kept_idx)} — "
+                                "an update failed a gate the planner "
+                                "could not predict (e.g. a natural "
+                                "nonfinite or norm violation); rerun "
+                                "with fuse_rounds=1")
+                job.record(ar, state.params, payloads, wire_payloads, stats,
+                           round_metrics[r] if collect else None,
+                           quality[r], wall_each, B, amortized=True)
         loop0 += chunk
         cur, out = nxt, nxt_out
-    result.final_params = state.params
+    job.result.final_params = state.params

@@ -204,13 +204,12 @@ class SyncScheduler:
 
     @property
     def max_participants(self) -> int:
-        """Per-round cohort size m = round(sample_fraction · K), the
-        single source of that formula: ``plan`` samples exactly m
-        (dropout only removes), fused execution sizes its static (S, B)
-        plan to it, and the driver's amplification q is m/K.
+        """Per-round cohort size m (``cohort_size``): ``plan`` samples
+        exactly m (dropout only removes), fused execution sizes its
+        static (S, B) plan to it, and the driver's amplification q is
+        m/K.
         """
-        m = max(1, int(round(self.cfg.sample_fraction * self.num_clients)))
-        return min(m, self.num_clients)
+        return cohort_size(self.cfg, self.num_clients)
 
     def plan_horizon(self, start_round: int, horizon: int,
                      server_version: int = 0) -> List[RoundPlan]:
@@ -300,6 +299,14 @@ class FedBuffScheduler:
         """Server versions some in-flight client is still training from
         (the driver keeps those param snapshots alive)."""
         return {v0 for v0, _ in self.in_flight.values()}
+
+
+def cohort_size(cfg: FedConfig, num_clients: int) -> int:
+    """Per-round cohort size m = round(sample_fraction · K) of a sync
+    job, the single source of that formula, read before any scheduler
+    is built by the driver's refusals."""
+    m = max(1, int(round(cfg.sample_fraction * num_clients)))
+    return min(m, num_clients)
 
 
 def make_scheduler(cfg: FedConfig, num_clients: int, seed: int = 0,

@@ -6,12 +6,15 @@ boundary, a transfer-guard proof that the fused hot loop never crosses
 the host, the <= 2-compiles property on a varying-P trace, and the
 eval_every / evaluated-flag record semantics.
 """
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
 
 from _trace_guards import assert_compiles, assert_no_transfers
-from repro.config import FaultConfig, FedConfig, ScbfConfig, TrainConfig
+from repro.config import (FaultConfig, FedConfig, ObsConfig, ScbfConfig,
+                          TrainConfig)
 from repro.core.scbf import run_federated
 from repro.data.medical import generate_cohort
 from repro.fed.cohort import bucket_size
@@ -61,6 +64,8 @@ def _assert_trajectories_match(per_round, fused, bitwise_params=True):
         assert ra.dense_bytes == rb.dense_bytes
         assert ra.upload_fraction == rb.upload_fraction
         assert ra.epsilon == rb.epsilon
+        assert ra.hidden_sizes == rb.hidden_sizes
+        assert ra.flops_proxy == rb.flops_proxy
     if bitwise_params:
         assert _params_bitwise_equal(per_round.final_params,
                                      fused.final_params)
@@ -235,6 +240,60 @@ def test_fused_sequential_engine_falls_back(cohort):
 def test_fuse_rounds_validation(cohort):
     with pytest.raises(ValueError):
         run_federated(cohort, _tcfg(0), method="scbf", mlp_features=FEATS)
+
+
+@pytest.mark.parametrize("fed_kw,scbf_kw,message", [
+    (dict(max_update_norm=1.0), {}, "host-side norm gate"),
+    ({}, dict(dp_noise_multiplier=-1.0), "must be >= 0"),
+    (dict(sample_fraction=0.4, min_valid_participants=3), {},
+     "can never be met"),
+], ids=["fused", "dp", "quorum"])
+def test_refusals_come_before_the_cohort_reaches_the_device(
+        cohort, monkeypatch, fed_kw, scbf_kw, message):
+    """Every refusal is decided from the configuration alone: none of
+    them waits for the engine to copy the cohort to the device."""
+    def _no_engine(*a, **k):
+        raise AssertionError("make_engine ran before the refusal")
+    monkeypatch.setattr("repro.fed.engine.make_engine", _no_engine)
+    with pytest.raises(ValueError, match=message):
+        run_federated(cohort, _tcfg(3, scbf_kw=scbf_kw, **fed_kw),
+                      method="scbf", mlp_features=FEATS)
+
+
+# the event envelope's timestamp, and the fields where the two loops
+# differ by design: the fused loop amortises one chunk's wall over its
+# rounds, trains a run-constant slot count and evaluates at chunk
+# boundaries only
+_LOOP_SHAPED = ("ts", "wall", "wall_is_amortized", "slots", "evaluated")
+
+
+# fused FedAvg's model is allclose to the per-round one, not bitwise
+# (test_fused_fedavg_matches_per_round), so its train loss differs in
+# the last bits from the second round on
+@pytest.mark.parametrize("method,kw,fuse,inexact", [
+    ("scbf", dict(loops=7, K=8, batch=32, sample_fraction=0.5,
+                  dropout_rate=0.25,
+                  scbf_kw=dict(dp_noise_multiplier=1.0, dp_clip_norm=1.0)),
+     3, ()),
+    ("scbf", dict(loops=8, scbf_kw=dict(prune=True, prune_impl="mask",
+                                        prune_rate=0.2, prune_total=0.5)),
+     4, ()),
+    ("fedavg", dict(loops=5), 3, ("train_loss",)),
+], ids=["scbf-dp-sampled", "scbfwp-mask", "fedavg"])
+def test_round_events_match_across_loops(cohort, method, kw, fuse, inexact):
+    """The per-round and the fused loop send the same ``round`` event for
+    every round (docs/OBSERVABILITY.md), device metrics included."""
+    def rounds(S):
+        cfg = dataclasses.replace(_tcfg(S, **kw),
+                                  obs=ObsConfig(device_metrics=True))
+        _, rec = _recorded(cohort, cfg, method)
+        return [{k: v for k, v in e.items()
+                 if k not in _LOOP_SHAPED + inexact}
+                for e in rec.events if e["ev"] == "round"]
+    per_round, fused = rounds(1), rounds(fuse)
+    assert len(per_round) == len(fused) == kw["loops"]
+    for a, b in zip(per_round, fused):
+        assert a == b
 
 
 # ---------------------------------------------------------------------------
